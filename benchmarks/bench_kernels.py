@@ -1,7 +1,7 @@
 """Per-kernel micro-benchmarks.
 
-On this CPU container the Pallas kernels execute under interpret=True
-(Python), so wall-times are NOT TPU-meaningful; what we report per kernel:
+This benchmark runs the Pallas kernels under interpret=True (Python, on
+the CPU), so wall-times are NOT TPU-meaningful; what it reports per kernel:
   * correctness vs the ref.py oracle at a production-relevant shape,
   * analytic FLOPs and HBM bytes, arithmetic intensity, and the v5e
     roofline-bound µs (the number the TPU run would be judged against),

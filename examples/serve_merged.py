@@ -8,9 +8,9 @@ remaining K*/V* reads.  The dense cache caps concurrency at
 spends the same bytes on a block pool (vLLM-style: free-list allocator,
 per-request block tables, prefix sharing with copy-on-write), so a
 mixed-length request mix runs many more streams per HBM byte — watch
-``peak streams`` between the two runs.  (On the CPU container the
-absolute tok/s is illustrative; the bandwidth accounting is the
-TPU-relevant part.)
+``peak streams`` between the two runs.  (Run on the CPU, the absolute
+tok/s is illustrative; the bandwidth accounting is the TPU-relevant
+part.  ``chip_smoke.py`` is the path that runs on a TPU.)
 
   PYTHONPATH=src python examples/serve_merged.py [--arch llama3.2-1b]
                                                  [--cache dense|paged]

@@ -127,18 +127,8 @@ def decode_ms_per_token(n_weights: int, bytes_per_weight: int = 2,
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """Normalize a jitted ``Compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns a flat dict; newer versions (0.4.37 here) return a
-    list with one dict per executable module.  Sum the per-module entries
-    into one dict so callers can ``.get("flops")`` uniformly.  Lives here
-    (not in launch.dryrun) because importing dryrun has side effects —
-    its XLA_FLAGS mutation forces a 512-device host platform."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        merged: Dict[str, float] = {}
-        for c in cost:
-            for k, v in (c or {}).items():
-                merged[k] = merged.get(k, 0.0) + float(v)
-        return merged
-    return dict(cost or {})
+    """A jitted ``Compiled.cost_analysis()`` as a plain dict (empty when
+    the backend reports none), so callers can ``.get("flops")``.  Lives
+    here (not in launch.dryrun) because importing dryrun has side effects
+    — its XLA_FLAGS mutation forces a 512-device host platform."""
+    return dict(compiled.cost_analysis() or {})

@@ -1,43 +1,43 @@
-"""Single-token GQA decode attention vs a (ring-buffer) KV cache.
+"""Single-token GQA decode attention vs a dense KV cache or a paged pool.
 
-Flash-decoding adapted to TPU: grid = (batch, kv_heads, kv_blocks); the kv
-block axis is sequential ("arbitrary") and carries the online-softmax state
-for the G = Hq/Hkv query heads of this kv head in VMEM scratch.  Cache
-validity/causality/sliding-window are evaluated from an explicit per-slot
-position array (−1 = empty slot), which is what the serving layer's ring
-buffer maintains — the kernel itself is layout-agnostic.
+Flash-decoding adapted to TPU: grid = (batch, kv_blocks); the kv block
+axis is sequential ("arbitrary") and carries the online-softmax state of
+all Hq = Hkv·G query heads in VMEM scratch.  Each grid step DMAs ONE kv
+block holding every kv head — a (bk, Hkv, D) stretch of the cache's
+native (B, S, Hkv, D) layout, or one physical (bs, Hkv, D) page of a
+(NB, bs, Hkv, D) pool — and updates the G query heads of each kv head in
+turn.  Cache validity/causality/sliding-window are evaluated from
+positions (−1 = empty slot); the kernel is layout-agnostic about rings.
 
-The (G, bk) score matmul is small on the M dimension by nature of decode;
-the kernel keeps D and bk MXU-aligned which is where the FLOPs are.
+One kernel serves both projection styles.  The query arrives grouped as
+(B, Hkv, G, D): a separately projected q (generic), or the RoPE'd
+residual stream viewed as heads — the paper's merged (Q/P-removed)
+serving path, where d_model = Hq·D and the (B, d_model) stream IS the
+query (Fig 1b) and the output lands straight in the FFN-input basis.  In
+this layout the two differ only in where q came from, so the cache is
+read untransposed either way.
 
-Two variants:
-  * ``decode_attention_bhsd`` — generic: q is a separately-projected
-    (B, Hkv, G, D) tensor, the cache arrives transposed to head-major
-    (B, Hkv, S, D).
-  * ``decode_attention_merged_bsd`` — the paper's merged (Q/P-removed)
-    serving fast path: there is NO q projection, the RoPE'd residual
-    stream (B, d_model) *is* the query (d_model = Hq·D for merged
-    configs, paper Fig 1b).  The kernel takes the stream reshaped
-    (bitcast, no copy) to (B, Hq, D) and reads K*/V* in the serving
-    cache's NATIVE (B, S, Hkv, D) layout — no per-step head-major
-    transpose of the whole cache — then writes the attention output
-    straight into the FFN-input basis (no P projection exists).
+TPU block rule (shared with ``flash_attention`` and ``ssd_scan``): the
+last two dims of every block are whole array dims — (G, D) of the query,
+(Hkv, D) of a cache row or page — and per-position vectors travel as
+(1, n) rows of a (-1, 1, n) view.  Scalars (query positions, block
+tables, page scales) are scalar-prefetch operands in SMEM.
 
-Paged variants (``decode_attention_paged_bhsd`` /
-``decode_attention_paged_merged_bsd``): the cache is a POOL of physical
-pages (n_blocks, block_size, Hkv, D) shared by all slots, and each slot
-owns a per-request block table (B, MB) of physical page ids (-1 =
-unmapped).  The sequential kv axis of the grid walks LOGICAL blocks; the
-block table is a scalar-prefetch operand so the k/v BlockSpec index_maps
-gather the mapped physical page (clamped to page 0 when unmapped — the
-in-kernel mask zeroes those scores).  kv positions are not stored: with
-absolute addressing logical block j covers positions [j·bs, (j+1)·bs);
-with ring addressing (``ring_blocks`` > 0 — sliding-window tables bounded
-at ceil(window/bs)+1 recycled slots, see ``kernels.paging``) slot j holds
-the latest absolute block ≡ j (mod ring) not beyond the query's block, so
-the kernel reconstructs positions from the grid index and the query
-position.  Either way the online-softmax update is shared with the dense
-variants unchanged.
+Paged variant (``decode_attention_paged_bsd``): each slot owns a
+per-request block table (B, MB) of physical page ids (-1 = unmapped).
+The sequential kv axis of the grid walks LOGICAL blocks; the block table
+is a scalar-prefetch operand so the k/v BlockSpec index_maps gather the
+mapped physical page (clamped to page 0 when unmapped — the in-kernel
+mask zeroes those scores).  kv positions are not stored: with absolute
+addressing logical block j covers positions [j·bs, (j+1)·bs); with ring
+addressing (``ring_blocks`` > 0 — sliding-window tables bounded at
+ceil(window/bs)+1 recycled slots, see ``kernels.paging``) slot j holds
+the latest absolute block ≡ j (mod ring) not beyond the query's block,
+so the kernel reconstructs positions from the grid index and the query
+position.  With ``k_scale``/``v_scale`` the pool holds int8 pages with
+one float32 scale per (page, kv head) (``kernels.quant``): the scales
+ride along as extra scalar-prefetch operands and each (bs, D) tile is
+dequantized in VMEM, so no full-precision pool ever exists in HBM.
 """
 from __future__ import annotations
 
@@ -49,18 +49,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG = -1e30
 
 
 def _online_softmax_block(ik, q, k, v, kpos, qpos, m_scr, l_scr, acc_scr,
                           *, scale: float, window: int):
-    """Shared flash-decoding state update for one (G, bk) kv block.
+    """Shared flash-decoding state update of one kv head for one kv block.
 
-    ``q`` (G, D) and ``k``/``v`` (bk, D) are already sliced from the
-    variant-specific block layout; the m/l/acc scratch carries the
-    online-softmax state across the sequential kv-block axis.
+    ``q`` (G, D) and ``k``/``v`` (bk, D) are already sliced from the block
+    layout, ``kpos`` is the (1, bk) row of the block's positions; the
+    m/l/acc scratch carries the online-softmax state across the
+    sequential kv-block axis.
     """
     @pl.when(ik == 0)
     def _init():
@@ -75,7 +74,7 @@ def _online_softmax_block(ik, q, k, v, kpos, qpos, m_scr, l_scr, acc_scr,
     ok = (kpos >= 0) & (kpos <= qpos)
     if window > 0:
         ok &= qpos - kpos < window
-    mask = jnp.broadcast_to(ok[None, :], s.shape)
+    mask = jnp.broadcast_to(ok, s.shape)
     s = jnp.where(mask, s, NEG)
 
     m_prev = m_scr[:, :1]
@@ -90,38 +89,62 @@ def _online_softmax_block(ik, q, k, v, kpos, qpos, m_scr, l_scr, acc_scr,
     m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
 
 
-def _finish_output(l_scr, acc_scr):
-    denom = l_scr[:, :1]
-    denom = jnp.where(denom == 0.0, 1.0, denom)
-    return acc_scr[...] / denom
+def _attend_all_heads(ik, nk, q_ref, k_ref, v_ref, o_ref, kpos, qpos,
+                      m_scr, l_scr, acc_scr, *, scale: float, window: int,
+                      dequant=None):
+    """Run the online-softmax update for every kv head of one block, and
+    write the normalised output after the last block.
 
-
-def _decode_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale: float, window: int,
-                   nk: int):
-    ik = pl.program_id(2)
-    _online_softmax_block(ik, q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
-                          kpos_ref[0], qpos_ref[0, 0], m_scr, l_scr, acc_scr,
-                          scale=scale, window=window)
+    ``q_ref`` (1, Hkv, G, D), ``k_ref``/``v_ref`` (1, bk, Hkv, D); kv head
+    h reads the strided (bk, D) slice ``[0, :, h]``.  ``dequant(h, k, v)``
+    (int8 pools) scales the slices of kv head h."""
+    n_kv = k_ref.shape[2]
+    for h in range(n_kv):
+        k = k_ref[0, :, h].astype(jnp.float32)
+        v = v_ref[0, :, h].astype(jnp.float32)
+        if dequant is not None:
+            k, v = dequant(h, k, v)
+        _online_softmax_block(ik, q_ref[0, h], k, v, kpos, qpos,
+                              m_scr.at[h], l_scr.at[h], acc_scr.at[h],
+                              scale=scale, window=window)
 
     @pl.when(ik == nk - 1)
     def _finish():
-        o_ref[0, 0] = _finish_output(l_scr, acc_scr).astype(o_ref.dtype)
+        denom = l_scr[:, :, :1]
+        denom = jnp.where(denom == 0.0, 1.0, denom)
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
-def decode_attention_bhsd(
-    q: jnp.ndarray,  # (B, Hkv, G, D) — grouped query heads
-    k: jnp.ndarray,  # (B, Hkv, S, D)
-    v: jnp.ndarray,  # (B, Hkv, S, D)
+def _scratch(n_kv: int, G: int, D: int):
+    return [pltpu.VMEM((n_kv, G, 128), jnp.float32),
+            pltpu.VMEM((n_kv, G, 128), jnp.float32),
+            pltpu.VMEM((n_kv, G, D), jnp.float32)]
+
+
+def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, scale: float, window: int,
+                   nk: int):
+    b = pl.program_id(0)
+    ik = pl.program_id(1)
+    _attend_all_heads(ik, nk, q_ref, k_ref, v_ref, o_ref, kpos_ref[0],
+                      qpos_ref[b], m_scr, l_scr, acc_scr,
+                      scale=scale, window=window)
+
+
+def decode_attention_bsd(
+    q: jnp.ndarray,  # (B, Hkv, G, D) — grouped query heads (or stream view)
+    k: jnp.ndarray,  # (B, S, Hkv, D) — cache, NATIVE serving layout
+    v: jnp.ndarray,  # (B, S, Hkv, D)
     kv_positions: jnp.ndarray,  # (B, S) int32; -1 marks empty slots
-    q_position: jnp.ndarray,  # (B, 1) int32
+    q_position: jnp.ndarray,  # (B,) int32
     *,
     sliding_window: int = 0,
     block_k: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """Decode attention against a dense per-slot cache -> (B, Hkv, G, D)."""
     B, Hkv, G, D = q.shape
-    S = k.shape[2]
+    S = k.shape[1]
     bk = min(block_k, S)
     assert S % bk == 0, (S, bk)
     nk = S // bk
@@ -129,115 +152,45 @@ def decode_attention_bhsd(
 
     kernel = functools.partial(_decode_kernel, scale=scale,
                                window=sliding_window, nk=nk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, nk),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, G, D), lambda b, j, qp: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bk, Hkv, D), lambda b, j, qp: (b, j, 0, 0)),
+            pl.BlockSpec((1, bk, Hkv, D), lambda b, j, qp: (b, j, 0, 0)),
+            # positions as (1, bk) rows of a (B·nk, 1, bk) view
+            pl.BlockSpec((1, 1, bk), lambda b, j, qp, nk=nk: (b * nk + j, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, G, D), lambda b, j, qp: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(Hkv, G, D),
+    )
     return pl.pallas_call(
         kernel,
-        grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="decode_attention",
-    )(q, k, v, kv_positions, q_position)
-
-
-def _decode_kernel_merged(u_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
-                          m_scr, l_scr, acc_scr, *, scale: float, window: int,
-                          nk: int):
-    ik = pl.program_id(2)
-    # the stream block holds this kv head's G query heads contiguously;
-    # k/v blocks are sliced from the NATIVE (B, S, Hkv, D) cache layout
-    _online_softmax_block(ik, u_ref[0], k_ref[0, :, 0], v_ref[0, :, 0],
-                          kpos_ref[0], qpos_ref[0, 0], m_scr, l_scr, acc_scr,
-                          scale=scale, window=window)
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        o_ref[0] = _finish_output(l_scr, acc_scr).astype(o_ref.dtype)
-
-
-def decode_attention_merged_bsd(
-    u: jnp.ndarray,  # (B, Hq, D) — RoPE'd residual stream viewed as heads
-    k: jnp.ndarray,  # (B, S, Hkv, D) — K* cache, NATIVE serving layout
-    v: jnp.ndarray,  # (B, S, Hkv, D) — V* cache, native layout
-    kv_positions: jnp.ndarray,  # (B, S) int32; -1 marks empty slots
-    q_position: jnp.ndarray,  # (B, 1) int32
-    *,
-    sliding_window: int = 0,
-    block_k: int = 512,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Merged-weight decode: stream-as-query, no q operand to project.
-
-    Grid and softmax state as in ``decode_attention_bhsd``; the blocking
-    differs so K*/V* stream from the cache without a head-major transpose
-    (the transpose would rewrite the whole cache every decoded token) and
-    the output lands as (B, Hq, D) — a bitcast of the (B, d_model)
-    FFN-input stream the merged block consumes next.
-    """
-    B, Hq, D = u.shape
-    S, Hkv = k.shape[1], k.shape[2]
-    assert Hq % Hkv == 0, (Hq, Hkv)
-    G = Hq // Hkv
-    bk = min(block_k, S)
-    assert S % bk == 0, (S, bk)
-    nk = S // bk
-    scale = 1.0 / math.sqrt(D)
-
-    kernel = functools.partial(_decode_kernel_merged, scale=scale,
-                               window=sliding_window, nk=nk)
-    return pl.pallas_call(
-        kernel,
-        grid=(B, Hkv, nk),
-        in_specs=[
-            # kv head h owns query heads [h*G, (h+1)*G) of the stream
-            pl.BlockSpec((1, G, D), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, G, D), lambda b, h, j: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), u.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="decode_attention_merged",
-    )(u, k, v, kv_positions, q_position)
+    )(q_position.astype(jnp.int32), q, k, v,
+      kv_positions.astype(jnp.int32).reshape(B * nk, 1, bk))
 
 
 # ---------------------------------------------------------------------------
-# paged variants: block-table gather over a physical page pool
+# paged variant: block-table gather over a physical page pool
 # ---------------------------------------------------------------------------
 
 def _paged_kpos(block_id, j, bs, qpos, ring):
-    """Positions covered by table slot ``j`` (-1 everywhere if unmapped).
+    """(1, bs) positions covered by table slot ``j`` (-1 if unmapped).
 
     Absolute addressing (``ring`` = 0): slot j IS logical block j.  Ring
     addressing: slot j holds the latest absolute block ≡ j (mod ring) the
     request has entered — reconstructed from the query's block ``lb``;
     never-entered slots (b < 0) are unmapped anyway but masked for safety.
-    2D iota then rank-reduce: TPU vector units have no 1D iota."""
-    off = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
+    2D iota: TPU vector units have no 1D iota."""
+    off = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     if ring:
         lb = qpos // bs
         b = lb - ((lb + ring - j) % ring)
@@ -245,325 +198,91 @@ def _paged_kpos(block_id, j, bs, qpos, ring):
     return jnp.where(block_id >= 0, j * bs + off, -1)
 
 
-def _decode_kernel_paged(bt_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale: float, window: int,
-                         bs: int, nb: int, ring: int):
+def _paged_kernel(*refs, scale: float, window: int, bs: int, nb: int,
+                  ring: int, quantized: bool):
+    if quantized:
+        (bt_ref, qpos_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
+         m_scr, l_scr, acc_scr) = refs
+    else:
+        (bt_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
+         m_scr, l_scr, acc_scr) = refs
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    kpos = _paged_kpos(bt_ref[b, j], j, bs, qpos_ref[0, 0], ring)
-    _online_softmax_block(j, q_ref[0, 0], k_ref[0, :, 0], v_ref[0, :, 0],
-                          kpos, qpos_ref[0, 0], m_scr, l_scr, acc_scr,
-                          scale=scale, window=window)
+    j = pl.program_id(1)
+    qpos = qpos_ref[b]
+    dequant = None
+    if quantized:
+        n_kv = k_ref.shape[2]
+        row = jnp.maximum(bt_ref[b, j], 0) * n_kv  # the gathered page's scales
 
-    @pl.when(j == nb - 1)
-    def _finish():
-        o_ref[0, 0] = _finish_output(l_scr, acc_scr).astype(o_ref.dtype)
+        def dequant(h, k, v):
+            return k * ks_ref[row + h], v * vs_ref[row + h]
+
+    _attend_all_heads(j, nb, q_ref, k_ref, v_ref, o_ref,
+                      _paged_kpos(bt_ref[b, j], j, bs, qpos, ring), qpos,
+                      m_scr, l_scr, acc_scr, scale=scale, window=window,
+                      dequant=dequant)
 
 
-def decode_attention_paged_bhsd(
-    q: jnp.ndarray,  # (B, Hkv, G, D) — grouped query heads
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — physical page pool
+def decode_attention_paged_bsd(
+    q: jnp.ndarray,  # (B, Hkv, G, D) — grouped query heads (or stream view)
+    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — physical page pool (int8 if q8)
     v_pool: jnp.ndarray,  # (NB, bs, Hkv, D)
     block_tables: jnp.ndarray,  # (B, MB) int32 physical page ids; -1 unmapped
-    q_position: jnp.ndarray,  # (B, 1) int32
+    q_position: jnp.ndarray,  # (B,) int32
     *,
+    k_scale=None,  # (NB, Hkv) float32 per-(page, head) scales — int8 pools
+    v_scale=None,  # (NB, Hkv) float32
     sliding_window: int = 0,
     ring_blocks: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Generic paged decode: like ``decode_attention_bhsd`` but the kv-block
-    axis walks the slot's block table and gathers physical pages.  The pool
-    keeps the serving cache's native (…, bs, Hkv, D) page layout — pages are
-    written once at append time and never transposed.  ``ring_blocks`` > 0
-    means the table is ring-addressed (windowed requests recycle pages; see
-    ``kernels.paging``) and slot positions are reconstructed from the query
-    position."""
+    """Paged decode -> (B, Hkv, G, D): the kv-block axis walks the slot's
+    block table and gathers one physical page (all kv heads) per step.
+    The pool keeps the serving cache's native (…, bs, Hkv, D) page layout
+    — pages are written once at append time and never transposed.
+    ``ring_blocks`` > 0 means the table is ring-addressed (windowed
+    requests recycle pages; see ``kernels.paging``) and slot positions are
+    reconstructed from the query position.  ``k_scale``/``v_scale`` switch
+    to an int8 pool dequantized per tile in VMEM."""
     B, Hkv, G, D = q.shape
     NB, bs = k_pool.shape[0], k_pool.shape[1]
     MB = block_tables.shape[1]
+    quantized = k_scale is not None
     scale = 1.0 / math.sqrt(D)
 
-    kernel = functools.partial(_decode_kernel_paged, scale=scale,
+    kernel = functools.partial(_paged_kernel, scale=scale,
                                window=sliding_window, bs=bs, nb=MB,
-                               ring=ring_blocks)
+                               ring=ring_blocks, quantized=quantized)
 
-    def page(b, h, j, bt):  # physical page for logical block j of slot b
-        return (jnp.maximum(bt[b, j], 0), 0, h, 0)
+    def page(b, j, bt, *_):  # physical page for logical block j of slot b
+        return (jnp.maximum(bt[b, j], 0), 0, 0, 0)
 
+    def head(b, j, *_):
+        return (b, 0, 0, 0)
+
+    prefetch = [block_tables.astype(jnp.int32), q_position.astype(jnp.int32)]
+    if quantized:  # flat (NB·Hkv,) scale vectors: page-major, like the pool
+        prefetch += [k_scale.astype(jnp.float32).reshape(-1),
+                     v_scale.astype(jnp.float32).reshape(-1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, Hkv, MB),
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, MB),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, 1), lambda b, h, j, bt: (b, 0)),
+            pl.BlockSpec((1, Hkv, G, D), head),
+            pl.BlockSpec((1, bs, Hkv, D), page),
+            pl.BlockSpec((1, bs, Hkv, D), page),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hkv, G, D), head),
+        scratch_shapes=_scratch(Hkv, G, D),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="decode_attention_paged",
-    )(block_tables.astype(jnp.int32), q, k_pool, v_pool, q_position)
-
-
-def _decode_kernel_paged_merged(bt_ref, u_ref, k_ref, v_ref, qpos_ref, o_ref,
-                                m_scr, l_scr, acc_scr, *, scale: float,
-                                window: int, bs: int, nb: int, ring: int):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    kpos = _paged_kpos(bt_ref[b, j], j, bs, qpos_ref[0, 0], ring)
-    _online_softmax_block(j, u_ref[0], k_ref[0, :, 0], v_ref[0, :, 0],
-                          kpos, qpos_ref[0, 0], m_scr, l_scr, acc_scr,
-                          scale=scale, window=window)
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        o_ref[0] = _finish_output(l_scr, acc_scr).astype(o_ref.dtype)
-
-
-def decode_attention_paged_merged_bsd(
-    u: jnp.ndarray,  # (B, Hq, D) — RoPE'd residual stream viewed as heads
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — K* page pool, native layout
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — V* page pool
-    block_tables: jnp.ndarray,  # (B, MB) int32 physical page ids; -1 unmapped
-    q_position: jnp.ndarray,  # (B, 1) int32
-    *,
-    sliding_window: int = 0,
-    ring_blocks: int = 0,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Merged (Q/P-removed) paged decode: stream-as-query over a page pool.
-
-    Combines the paper's serving fast path (no Q projection to read, output
-    straight into the FFN-input basis) with vLLM-style paging — per token
-    the only HBM traffic besides the stream is K*/V* weight reads and the
-    slot's mapped pages.  ``ring_blocks`` as in
-    ``decode_attention_paged_bhsd``."""
-    B, Hq, D = u.shape
-    NB, bs, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    assert Hq % Hkv == 0, (Hq, Hkv)
-    G = Hq // Hkv
-    MB = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-
-    kernel = functools.partial(_decode_kernel_paged_merged, scale=scale,
-                               window=sliding_window, bs=bs, nb=MB,
-                               ring=ring_blocks)
-
-    def page(b, h, j, bt):
-        return (jnp.maximum(bt[b, j], 0), 0, h, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, Hkv, MB),
-        in_specs=[
-            # kv head h owns query heads [h*G, (h+1)*G) of the stream
-            pl.BlockSpec((1, G, D), lambda b, h, j, bt: (b, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, 1), lambda b, h, j, bt: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, G, D), lambda b, h, j, bt: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), u.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="decode_attention_paged_merged",
-    )(block_tables.astype(jnp.int32), u, k_pool, v_pool, q_position)
-
-
-# ---------------------------------------------------------------------------
-# quantized (paged_q8) variants: int8 page pools, in-kernel dequant
-# ---------------------------------------------------------------------------
-#
-# The pools are the same physical pages quantized to int8 with one float32
-# scale per (page, kv head) (see ``kernels.quant``).  The scale arrays ride
-# along as EXTRA scalar-prefetch operands next to the block table — (NB,
-# Hkv) is tiny, lives in SMEM, and the kernel looks up the gathered page's
-# scale with the same ``max(bt[b, j], 0)`` clamp the BlockSpec gather uses
-# (unmapped slots read page 0's scale; the position mask zeroes those
-# scores regardless).  Dequant happens on the (bs, D) tile already in
-# VMEM — `ints.astype(f32) * scale` — so no full-precision pool is ever
-# materialized in HBM; everything downstream of the per-tile dequant is
-# the fp kernels' shared online-softmax update, unchanged.
-
-def _decode_kernel_paged_q8(bt_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
-                            qpos_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                            scale: float, window: int, bs: int, nb: int,
-                            ring: int):
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-    pg = jnp.maximum(bt_ref[b, j], 0)
-    kd = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[pg, h]
-    vd = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[pg, h]
-    kpos = _paged_kpos(bt_ref[b, j], j, bs, qpos_ref[0, 0], ring)
-    _online_softmax_block(j, q_ref[0, 0], kd, vd,
-                          kpos, qpos_ref[0, 0], m_scr, l_scr, acc_scr,
-                          scale=scale, window=window)
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        o_ref[0, 0] = _finish_output(l_scr, acc_scr).astype(o_ref.dtype)
-
-
-def decode_attention_paged_q8_bhsd(
-    q: jnp.ndarray,  # (B, Hkv, G, D) — grouped query heads
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8
-    k_scale: jnp.ndarray,  # (NB, Hkv) float32 per-(page, head) scales
-    v_scale: jnp.ndarray,  # (NB, Hkv) float32
-    block_tables: jnp.ndarray,  # (B, MB) int32 physical page ids; -1 unmapped
-    q_position: jnp.ndarray,  # (B, 1) int32
-    *,
-    sliding_window: int = 0,
-    ring_blocks: int = 0,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Generic paged decode over an int8 pool: ``decode_attention_paged_bhsd``
-    with the gathered page dequantized in VMEM from its scalar-prefetched
-    (page, head) scale."""
-    B, Hkv, G, D = q.shape
-    NB, bs = k_pool.shape[0], k_pool.shape[1]
-    MB = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-
-    kernel = functools.partial(_decode_kernel_paged_q8, scale=scale,
-                               window=sliding_window, bs=bs, nb=MB,
-                               ring=ring_blocks)
-
-    def page(b, h, j, bt, ks, vs):  # physical page for logical block j
-        return (jnp.maximum(bt[b, j], 0), 0, h, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hkv, MB),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt, ks, vs: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, 1), lambda b, h, j, bt, ks, vs: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, j, bt, ks, vs: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="decode_attention_paged_q8",
-    )(block_tables.astype(jnp.int32), k_scale.astype(jnp.float32),
-      v_scale.astype(jnp.float32), q, k_pool, v_pool, q_position)
-
-
-def _decode_kernel_paged_q8_merged(bt_ref, ks_ref, vs_ref, u_ref, k_ref,
-                                   v_ref, qpos_ref, o_ref, m_scr, l_scr,
-                                   acc_scr, *, scale: float, window: int,
-                                   bs: int, nb: int, ring: int):
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-    pg = jnp.maximum(bt_ref[b, j], 0)
-    kd = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[pg, h]
-    vd = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[pg, h]
-    kpos = _paged_kpos(bt_ref[b, j], j, bs, qpos_ref[0, 0], ring)
-    _online_softmax_block(j, u_ref[0], kd, vd,
-                          kpos, qpos_ref[0, 0], m_scr, l_scr, acc_scr,
-                          scale=scale, window=window)
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        o_ref[0] = _finish_output(l_scr, acc_scr).astype(o_ref.dtype)
-
-
-def decode_attention_paged_q8_merged_bsd(
-    u: jnp.ndarray,  # (B, Hq, D) — RoPE'd residual stream viewed as heads
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 K* page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 V* page pool
-    k_scale: jnp.ndarray,  # (NB, Hkv) float32 per-(page, head) scales
-    v_scale: jnp.ndarray,  # (NB, Hkv) float32
-    block_tables: jnp.ndarray,  # (B, MB) int32 physical page ids; -1 unmapped
-    q_position: jnp.ndarray,  # (B, 1) int32
-    *,
-    sliding_window: int = 0,
-    ring_blocks: int = 0,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Merged (Q/P-removed) paged decode over an int8 pool: the paper's
-    stream-as-query fast path with the page-pool HBM traffic quartered
-    (int8 vs f32 pages; the per-page scales are noise).  Dequant as in
-    ``decode_attention_paged_q8_bhsd``."""
-    B, Hq, D = u.shape
-    NB, bs, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    assert Hq % Hkv == 0, (Hq, Hkv)
-    G = Hq // Hkv
-    MB = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-
-    kernel = functools.partial(_decode_kernel_paged_q8_merged, scale=scale,
-                               window=sliding_window, bs=bs, nb=MB,
-                               ring=ring_blocks)
-
-    def page(b, h, j, bt, ks, vs):
-        return (jnp.maximum(bt[b, j], 0), 0, h, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hkv, MB),
-        in_specs=[
-            # kv head h owns query heads [h*G, (h+1)*G) of the stream
-            pl.BlockSpec((1, G, D), lambda b, h, j, bt, ks, vs: (b, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, bs, 1, D), page),
-            pl.BlockSpec((1, 1), lambda b, h, j, bt, ks, vs: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, G, D),
-                               lambda b, h, j, bt, ks, vs: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), u.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="decode_attention_paged_q8_merged",
-    )(block_tables.astype(jnp.int32), k_scale.astype(jnp.float32),
-      v_scale.astype(jnp.float32), u, k_pool, v_pool, q_position)
+        name="decode_attention_paged_q8" if quantized
+        else "decode_attention_paged",
+    )(*prefetch, q, k_pool, v_pool)

@@ -1,10 +1,11 @@
 """jit'd wrappers exposing the Pallas kernels in model-layer layouts.
 
-These adapt (B, S, H, D) model tensors to the kernels' head-major layouts,
-enforce blocking constraints, and fall back loudly (assert) rather than
-silently when an unsupported configuration is requested.  ``interpret=True``
-runs the kernel bodies in Python on CPU (how this container validates them);
-on TPU the same calls compile to Mosaic.
+These adapt model tensors to the kernels' block layouts (grouped-head and
+lane-column views, see the kernel modules' TPU block rule), pick block
+sizes the TPU can tile, and fail loudly (assert) rather than silently when
+an unsupported configuration is requested.  On TPU the calls compile to
+Mosaic; ``interpret=True`` runs the same kernel bodies in Python, which is
+how the CPU test suite validates them.
 """
 from __future__ import annotations
 
@@ -15,24 +16,26 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import (flash_attention_bhsd,
-                                           flash_attention_merged_bsd,
-                                           flash_attention_merged_q8_bsd)
-from repro.kernels.decode_attention import (decode_attention_bhsd,
-                                            decode_attention_merged_bsd,
-                                            decode_attention_paged_bhsd,
-                                            decode_attention_paged_merged_bsd,
-                                            decode_attention_paged_q8_bhsd,
-                                            decode_attention_paged_q8_merged_bsd)
+                                           flash_attention_merged_bsd)
+from repro.kernels.decode_attention import (decode_attention_bsd,
+                                            decode_attention_paged_bsd)
 from repro.kernels.paging import paged_ring_active
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
 def _pick_block(S: int, target: int) -> int:
-    """Largest divisor of S that is <= target (prefers multiples of 128)."""
-    b = min(target, S)
-    while S % b:
-        b -= 1
-    return b
+    """Largest divisor of S that is <= target and a multiple of 8 (the TPU
+    sublane tile), else S itself — a block may always span its dim."""
+    for b in range(min(target, S), 7, -1):
+        if S % b == 0 and b % 8 == 0:
+            return b
+    return S
+
+
+def _grouped(x: jnp.ndarray, n_kv_heads: int) -> jnp.ndarray:
+    """(B, Hq, D) query heads -> (B, Hkv, G, D): kv head h owns query
+    heads [h·G, (h+1)·G) (a free row-major reshape)."""
+    return x.reshape(x.shape[0], n_kv_heads, -1, x.shape[-1])
 
 
 @partial(jax.jit, static_argnames=("causal", "sliding_window", "interpret",
@@ -95,13 +98,11 @@ def flash_attention_merged(
     assert Hkv == n_kv_heads, (Hkv, n_kv_heads)
     D = k.shape[3]
     assert d % D == 0 and (d // D) % Hkv == 0, (d, D, Hkv)
-    bq = _pick_block(Sq, block_q)
-    bk = _pick_block(Sk, block_k)
-    out = flash_attention_merged_bsd(
-        u.reshape(B, Sq, d // D, D), k, v,
+    return flash_attention_merged_bsd(
+        u, k.reshape(B, Sk, Hkv * D), v.reshape(B, Sk, Hkv * D), d_head=D,
         causal=causal, sliding_window=sliding_window,
-        block_q=bq, block_k=bk, interpret=interpret)
-    return out.reshape(B, Sq, d)
+        block_q=_pick_block(Sq, block_q), block_k=_pick_block(Sk, block_k),
+        interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("sliding_window", "interpret", "block_k"))
@@ -116,16 +117,12 @@ def decode_attention(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    B, Hq, D = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
-    G = Hq // Hkv
-    bk = _pick_block(S, block_k)
-    out = decode_attention_bhsd(
-        q.reshape(B, Hkv, G, D),
-        k_cache.transpose(0, 2, 1, 3), v_cache.transpose(0, 2, 1, 3),
-        kv_positions.astype(jnp.int32), q_position.astype(jnp.int32)[:, None],
-        sliding_window=sliding_window, block_k=bk, interpret=interpret)
-    return out.reshape(B, Hq, D)
+    out = decode_attention_bsd(
+        _grouped(q, Hkv), k_cache, v_cache, kv_positions, q_position,
+        sliding_window=sliding_window, block_k=_pick_block(S, block_k),
+        interpret=interpret)
+    return out.reshape(q.shape)
 
 
 @partial(jax.jit, static_argnames=("n_kv_heads", "sliding_window", "interpret",
@@ -145,19 +142,20 @@ def decode_attention_merged(
     """Merged (Q/P-removed) decode fast path -> (B, d_model) FFN-input stream.
 
     No q projection exists in merged configs, so the stream is handed to
-    the kernel directly — the (B, Hq, D) view is a bitcast, and the cache
-    is consumed untransposed.
+    the kernel directly — the grouped-head view is a bitcast, and the
+    cache is consumed untransposed (the same kernel as
+    ``decode_attention``: in this layout the two differ only in where the
+    query came from).
     """
     B, d = u.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
     assert Hkv == n_kv_heads, (Hkv, n_kv_heads)
     D = k_cache.shape[3]
     assert d % D == 0 and (d // D) % Hkv == 0, (d, D, Hkv)
-    bk = _pick_block(S, block_k)
-    out = decode_attention_merged_bsd(
-        u.reshape(B, d // D, D), k_cache, v_cache,
-        kv_positions.astype(jnp.int32), q_position.astype(jnp.int32)[:, None],
-        sliding_window=sliding_window, block_k=bk, interpret=interpret)
+    out = decode_attention_bsd(
+        _grouped(u.reshape(B, d // D, D), Hkv), k_cache, v_cache,
+        kv_positions, q_position, sliding_window=sliding_window,
+        block_k=_pick_block(S, block_k), interpret=interpret)
     return out.reshape(B, d)
 
 
@@ -205,16 +203,13 @@ def decode_attention_paged(
     Ring addressing (windowed tables bounded at ceil(window/bs)+1 recycled
     slots) is derived from the static window and the table width — see
     ``kernels.paging`` — so callers never thread a ring flag."""
-    B, Hq, D = q.shape
-    Hkv = k_pool.shape[2]
-    G = Hq // Hkv
     ring = paged_ring_active(sliding_window, k_pool.shape[1],
                              block_tables.shape[1])
-    out = decode_attention_paged_bhsd(
-        q.reshape(B, Hkv, G, D), k_pool, v_pool,
-        block_tables.astype(jnp.int32), q_position.astype(jnp.int32)[:, None],
-        sliding_window=sliding_window, ring_blocks=ring, interpret=interpret)
-    return out.reshape(B, Hq, D)
+    out = decode_attention_paged_bsd(
+        _grouped(q, k_pool.shape[2]), k_pool, v_pool, block_tables,
+        q_position, sliding_window=sliding_window, ring_blocks=ring,
+        interpret=interpret)
+    return out.reshape(q.shape)
 
 
 @partial(jax.jit, static_argnames=("n_kv_heads", "sliding_window",
@@ -238,10 +233,10 @@ def decode_attention_paged_merged(
     assert d % D == 0 and (d // D) % Hkv == 0, (d, D, Hkv)
     ring = paged_ring_active(sliding_window, k_pool.shape[1],
                              block_tables.shape[1])
-    out = decode_attention_paged_merged_bsd(
-        u.reshape(B, d // D, D), k_pool, v_pool,
-        block_tables.astype(jnp.int32), q_position.astype(jnp.int32)[:, None],
-        sliding_window=sliding_window, ring_blocks=ring, interpret=interpret)
+    out = decode_attention_paged_bsd(
+        _grouped(u.reshape(B, d // D, D), Hkv), k_pool, v_pool, block_tables,
+        q_position, sliding_window=sliding_window, ring_blocks=ring,
+        interpret=interpret)
     return out.reshape(B, d)
 
 
@@ -266,16 +261,13 @@ def decode_attention_paged_q8(
     ``decode_attention_paged``: same block-table gather and ring
     derivation, with the gathered page dequantized inside the kernel from
     its scalar-prefetched scale."""
-    B, Hq, D = q.shape
-    Hkv = k_pool.shape[2]
-    G = Hq // Hkv
     ring = paged_ring_active(sliding_window, k_pool.shape[1],
                              block_tables.shape[1])
-    out = decode_attention_paged_q8_bhsd(
-        q.reshape(B, Hkv, G, D), k_pool, v_pool, k_scale, v_scale,
-        block_tables.astype(jnp.int32), q_position.astype(jnp.int32)[:, None],
+    out = decode_attention_paged_bsd(
+        _grouped(q, k_pool.shape[2]), k_pool, v_pool, block_tables,
+        q_position, k_scale=k_scale, v_scale=v_scale,
         sliding_window=sliding_window, ring_blocks=ring, interpret=interpret)
-    return out.reshape(B, Hq, D)
+    return out.reshape(q.shape)
 
 
 @partial(jax.jit, static_argnames=("n_kv_heads", "sliding_window",
@@ -300,9 +292,9 @@ def decode_attention_paged_q8_merged(
     assert d % D == 0 and (d // D) % Hkv == 0, (d, D, Hkv)
     ring = paged_ring_active(sliding_window, k_pool.shape[1],
                              block_tables.shape[1])
-    out = decode_attention_paged_q8_merged_bsd(
-        u.reshape(B, d // D, D), k_pool, v_pool, k_scale, v_scale,
-        block_tables.astype(jnp.int32), q_position.astype(jnp.int32)[:, None],
+    out = decode_attention_paged_bsd(
+        _grouped(u.reshape(B, d // D, D), Hkv), k_pool, v_pool, block_tables,
+        q_position, k_scale=k_scale, v_scale=v_scale,
         sliding_window=sliding_window, ring_blocks=ring, interpret=interpret)
     return out.reshape(B, d)
 
@@ -328,20 +320,24 @@ def flash_attention_merged_q8(
 ) -> jnp.ndarray:
     """Merged (Q/P-removed) flash PREFILL over int8 K*/V* — the q8 face of
     ``flash_attention_merged``; dequant happens tile-by-tile inside the
-    kernel (no full-precision K/V buffer in the program).  The kv block is
-    sized in whole serving pages, so ``block_k`` is a cap, not exact."""
+    kernel (no full-precision K/V buffer in the program).  The per-page
+    scales are expanded to per-key rows here (Sk·Hkv floats, noise beside
+    the int8 K/V), so the kv block need not align to pages."""
     assert kv_valid is None, "flash kernel: use the decode kernel for padded caches"
     B, Sq, d = u.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     assert Hkv == n_kv_heads, (Hkv, n_kv_heads)
     D = k.shape[3]
     assert d % D == 0 and (d // D) % Hkv == 0, (d, D, Hkv)
-    bq = _pick_block(Sq, block_q)
-    out = flash_attention_merged_q8_bsd(
-        u.reshape(B, Sq, d // D, D), k, v, k_scale, v_scale,
+    sg = Sk // k_scale.shape[1]  # serving page size: scale granularity
+    assert sg * k_scale.shape[1] == Sk, (Sk, k_scale.shape)
+    return flash_attention_merged_bsd(
+        u, k.reshape(B, Sk, Hkv * D), v.reshape(B, Sk, Hkv * D), d_head=D,
+        k_scale=jnp.repeat(k_scale, sg, axis=1),
+        v_scale=jnp.repeat(v_scale, sg, axis=1),
         causal=causal, sliding_window=sliding_window,
-        block_q=bq, block_k=block_k, interpret=interpret)
-    return out.reshape(B, Sq, d)
+        block_q=_pick_block(Sq, block_q), block_k=_pick_block(Sk, block_k),
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

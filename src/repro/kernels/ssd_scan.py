@@ -11,6 +11,12 @@ a chunk the SSD "duality" turns the recurrence into two MXU matmuls:
 All decay exponents are ≤ 0 (dt > 0, A < 0), so the exps are stable.
 Inputs are pre-activated: dt is post-softplus, a = dt·A.  The D-skip and
 gating/norm live in the ops wrapper / mamba2 module.
+
+TPU block rule (shared with the attention kernels): x/B/C are tiled
+head-major, (L, P) and (L, N) blocks of (B, H, S, ·); the per-position
+dt and a travel as (1, L) rows of a (B·H·nc, 1, L) view.  The prefix sum
+is a matmul with a triangular mask, and the two per-position columns the
+recurrence needs are read off the diagonal — no lane↔sublane reshape.
 """
 from __future__ import annotations
 
@@ -21,10 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
-
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, s_scr,
+def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, fin_ref, s_scr,
                 *, L: int, nc: int):
     c_idx = pl.program_id(2)
 
@@ -32,24 +36,31 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, s_scr,
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (L, P)
-    dt = dt_ref[0, :, 0:1].astype(jnp.float32)  # (L, 1) — squeezed head dim
-    a = a_ref[0, :, 0:1].astype(jnp.float32)  # (L, 1)
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)  # (L, N)
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)  # (L, N)
+    x = x_ref[0, 0].astype(jnp.float32)  # (L, P)
+    Bm = b_ref[0, 0].astype(jnp.float32)  # (L, N)
+    Cm = c_ref[0, 0].astype(jnp.float32)  # (L, N)
+    dt = dt_ref[0].astype(jnp.float32)  # (1, L)
+    a = a_ref[0].astype(jnp.float32)  # (1, L)
 
-    A_cum = jnp.cumsum(a, axis=0)  # (L, 1)
-    a_sum = A_cum[L - 1:L, :]  # (1, 1)
-    decay_out = jnp.exp(A_cum)  # (L, 1)
-    decay_end = jnp.exp(a_sum - A_cum)  # (L, 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+
+    def column(r):  # (1, L) row -> (L, 1) column, off the diagonal
+        return jnp.sum(jnp.where(rows == cols, r, 0.0), axis=1, keepdims=True)
+
+    # inclusive prefix sum: A_cum_i = Σ_{j<=i} a_j
+    A_cum = jax.lax.dot(a, (rows <= cols).astype(jnp.float32),
+                        preferred_element_type=jnp.float32)  # (1, L)
+    A_col = column(A_cum)  # (L, 1)
+    a_sum = jnp.sum(a, axis=1, keepdims=True)  # (1, 1)
+    decay_out = jnp.exp(A_col)  # (L, 1)
+    decay_end = jnp.exp(a_sum - A_col)  # (L, 1)
 
     # intra-chunk
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (L, L)
-    seg = A_cum - A_cum.reshape(1, L)  # (L, L): Acum_i − Acum_j
-    rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    kern = jnp.where(rows >= cols, jnp.exp(seg), 0.0) * CB * dt.reshape(1, L)
+    seg = A_col - A_cum  # (L, L): Acum_i − Acum_j
+    kern = jnp.where(rows >= cols, jnp.exp(seg), 0.0) * CB * dt
     y = jax.lax.dot(kern, x, preferred_element_type=jnp.float32)  # (L, P)
 
     # inter-chunk (state entering this chunk)
@@ -57,10 +68,10 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, s_scr,
     y += jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) * decay_out
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update
-    wB = Bm * decay_end * dt  # (L, N)
+    wB = Bm * decay_end * column(dt)  # (L, N)
     s_new = state * jnp.exp(a_sum) + jax.lax.dot_general(
         x, wB, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     s_scr[...] = s_new
@@ -86,30 +97,34 @@ def ssd_scan_pallas(
     L = chunk if S % chunk == 0 else S
     nc = S // L
 
+    def heads(t):  # (B, S, H, ·) -> (B, H, S, ·)
+        return t.transpose(0, 2, 1, 3)
+
+    def rows(t):  # (B, S, H) -> (B·H·nc, 1, L)
+        return t.transpose(0, 2, 1).reshape(B * H * nc, 1, L)
+
+    def seq(width):  # (L, width) tile of a head-major (B, H, S, width) array
+        return pl.BlockSpec((1, 1, L, width), lambda b, h, c: (b, h, c, 0))
+
+    row = pl.BlockSpec((1, 1, L), lambda b, h, c: ((b * H + h) * nc + c, 0, 0))
     kernel = functools.partial(_ssd_kernel, L=L, nc=nc)
     y, fin = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
-        in_specs=[
-            pl.BlockSpec((1, L, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, L, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, L, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, L, 1, N), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, L, 1, N), lambda b, h, c: (b, c, h, 0)),
-        ],
+        in_specs=[seq(P), seq(N), seq(N), row, row],
         out_specs=[
-            pl.BlockSpec((1, L, 1, P), lambda b, h, c: (b, c, h, 0)),
+            seq(P),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="ssd_scan",
-    )(x, dt, a, Bm, Cm)
-    return y, fin
+    )(heads(x), heads(Bm), heads(Cm), rows(dt), rows(a))
+    return heads(y), fin

@@ -17,6 +17,17 @@ pages, and the report adds the quantized-pool byte telemetry).
 
 Per-request serving stats (prompt_len, time-to-first-token, decode tok/s)
 come straight from ``Engine.generate``'s RequestResults.
+
+On a TPU, serve published widths cut in depth, with bfloat16 weights and
+the compiled Pallas kernels (the full 32-layer model in float32 does not
+fit one 16 GB chip):
+
+  PYTHONPATH=src python -m repro.launch.serve --arch mistral-7b \
+      --n-layers 4 --param-dtype bfloat16 --impl pallas --cache paged \
+      --prompt-len 256 --max-new 32 --max-len 512
+
+The persistent compile cache goes where ``repro.launch.compile_cache``
+says: ``$JAX_COMPILATION_CACHE_DIR`` if set, else ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -40,12 +51,20 @@ def main(argv=None):
     ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="cut the model to this depth (0: the config's)")
+    ap.add_argument("--impl", default="xla",
+                    choices=("xla", "pallas", "pallas_interpret"))
+    ap.add_argument("--param-dtype", default=None,
+                    choices=("float32", "bfloat16"),
+                    help="weight dtype (default: the config's)")
     args = ap.parse_args(argv)
 
     import jax
     import numpy as np
     from repro.configs import get_config, reduce_config
     from repro.core import merge_skipless
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import count_params, init_params
     from repro.serving import (Engine, PagedCacheAdapter,
                                PagedQ8CacheAdapter, ServeConfig)
@@ -53,12 +72,17 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_config(cfg)
+    if args.n_layers:
+        cfg = cfg.with_(n_layers=args.n_layers)
+    if args.param_dtype:
+        cfg = cfg.with_(param_dtype=args.param_dtype)
     if args.merged_from_skipless:
         cfg = cfg.with_(block_style="skipless")
     elif args.block_style:
         cfg = cfg.with_(block_style=args.block_style)
     cfg.validate_style()
 
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
     params = init_params(jax.random.PRNGKey(args.seed), cfg)
     n0 = count_params(params)
     if args.merged_from_skipless:
@@ -79,7 +103,7 @@ def main(argv=None):
         sc = ServeConfig(n_slots=args.slots, max_len=args.max_len,
                          temperature=args.temperature, seed=args.seed)
         cache = "dense"
-    eng = Engine(cfg, params, sc, cache=cache)
+    eng = Engine(cfg, params, sc, impl=args.impl, cache=cache)
     rng = np.random.RandomState(args.seed)
     prompts = [rng.randint(0, cfg.vocab_size, size=(args.prompt_len,))
                for _ in range(args.requests)]

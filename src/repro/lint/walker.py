@@ -10,7 +10,7 @@ kernel bodies, …).  PR 3 and PR 4 each hand-wrote that recursion inside a
 test; this module is the single copy the rule framework (and those tests)
 walk with.
 
-The traversal treats ANY ``jax.core.Jaxpr``/``ClosedJaxpr`` leaf found in
+The traversal treats ANY ``Jaxpr``/``ClosedJaxpr`` leaf found in
 an equation's params as an inner program — it doesn't enumerate primitive
 names, so new higher-order primitives are covered automatically.
 """
@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import Any, Iterator, List, Optional, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def as_jaxpr(program) -> jcore.Jaxpr:
@@ -129,8 +129,6 @@ def stablehlo_arg_attrs(lowered) -> List[Optional[str]]:
     import re
     txt = lowered.as_text() if hasattr(lowered, "as_text") else str(lowered)
     m = re.search(r"func\.func public @main\((.*?)\)\s*->", txt, re.S)
-    if m is None:  # fall back: some versions print non-public main
-        m = re.search(r"func\.func @main\((.*?)\)\s*->", txt, re.S)
     if m is None:
         raise ValueError("could not find @main signature in lowered module")
     sig = m.group(1)

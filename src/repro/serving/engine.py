@@ -264,6 +264,9 @@ class Engine:
             csh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                shd.evenly(self.kv.pspecs(rules), cshape,
                                           mesh))
+            # place the cache now: left to the first jitted call, the
+            # whole pool would sit on one device until a request arrives
+            self.kv.update(jax.device_put(self.kv.device_cache(), csh))
 
         def fwd(p, t, c):
             return forward_step(p, self.cfg, t, c, impl=impl,
