@@ -4,9 +4,11 @@
 (the default) it is the module singleton ``NULL`` — a ``NullObserver``
 whose every hook is a shared empty function and whose ``clock()`` returns
 0.0 without a syscall, so the off-mode serving path pays one attribute
-load + one no-op call per seam and nothing else (the zero-overhead-off
-guarantee ``benchmarks/bench_obs_overhead.py`` measures and asserts).
-With obs on, the observer binds a :class:`MetricsRegistry` and a
+load + one no-op call per seam and nothing else; ``tests/test_obs.py``
+holds that the greedy tokens are the same with the observer on and off.
+The program spans of :func:`repro.obs.trace.span` are not the observer's:
+a profiler session, not ``ServeConfig.obs``, turns them on.  With obs
+on, the observer binds a :class:`MetricsRegistry` and a
 :class:`TraceBuffer` and turns the engine's existing host timestamps into
 histograms and spans.
 
@@ -59,7 +61,6 @@ class NullObserver:
     queue_depth = _noop
     compile_event = _noop
     attach_engine = _noop
-    generate_done = _noop
     sched_iteration = _noop
     chunk_done = _noop
 
@@ -246,11 +247,6 @@ class Observer:
                             final=final)
         self.trace.complete(tr.slot_track(slot), "chunk", t0, t1,
                             rid=req.rid, start=start)
-
-    def generate_done(self, t0: float, t1: float, *, n_requests: int,
-                      n_tokens: int) -> None:
-        self.trace.complete(tr.engine_track(), "generate", t0, t1,
-                            n_requests=n_requests, n_tokens=n_tokens)
 
     # -- compile events -------------------------------------------------
     def compile_event(self, phase: str, bucket_len: Optional[int],

@@ -22,12 +22,50 @@ format, loadable directly in https://ui.perfetto.dev (or
 process/thread.  Timestamps are microseconds from the tracer's epoch,
 taken from ``time.perf_counter`` (monotonic; wall-clock NTP steps can
 never fold a span backwards).
+
+Program spans (``span``) are a second, separate record: events on the
+``jax.profiler`` host timeline, on the same clock as the device's ops,
+so each stretch in which the chip waits can be put down to the host
+code that ran in it.  They are written only while a profiler session is
+open (``jax.profiler.trace(dir)``), whatever ``ServeConfig.obs`` says;
+``SPANS`` lists them all.
 """
 from __future__ import annotations
 
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+#: every program span, with the layer whose host time it covers.  The
+#: ``repro.engine.*`` spans nest inside ``repro.engine.step``, which,
+#: like every ``repro.sched.*`` span, nests inside
+#: ``repro.sched.iteration`` (one per ``ScheduledEngine.step``).
+SPANS = {
+    "repro.sched.iteration": "scheduler",
+    "repro.sched.admit": "scheduler",
+    "repro.sched.plan": "scheduler",
+    "repro.sched.chunk": "scheduler",
+    "repro.sched.first_token": "scheduler",
+    "repro.sched.activate": "scheduler",
+    "repro.engine.step": "engine + page pool",
+    "repro.engine.pages": "engine + page pool",
+    "repro.engine.dispatch": "engine + page pool",
+    "repro.engine.sample": "engine + page pool",
+    "repro.engine.sync": "engine + page pool",
+    "repro.engine.emit": "engine + page pool",
+}
+
+
+def span(name: str, **counts: int) -> TraceAnnotation:
+    """A program span (a context manager) on the profiler's host timeline;
+    ``counts``, host integers the caller already holds, become the
+    event's stats, and ``set_metadata(**counts)`` on the entered span
+    adds more before it closes.  With no profiler session open it
+    records nothing and costs about a microsecond."""
+    return TraceAnnotation(name, **counts)
+
 
 #: (process id, process name) per track family — Perfetto groups tids
 #: under pids, so the engine / slots / requests render as three groups.

@@ -209,6 +209,12 @@ class KVCacheAdapter:
         no pool to report."""
         return {}
 
+    def span_counts(self):
+        """name -> host integer carried by each ``repro.engine.step``
+        span: the pool's pages mapped and free, read in O(1).  Dense
+        caches have no pool to report."""
+        return {}
+
 
 class DenseCacheAdapter(KVCacheAdapter):
     """Worst-case-length slot cache: every slot owns a ``max_len`` stretch
@@ -227,16 +233,17 @@ class DenseCacheAdapter(KVCacheAdapter):
         cfg = self.cfg
         dest = DensePrefillDest(cache_len=self.sc.max_len)
 
-        def fn(p, tk, vs, tl):
+        def prompt_prefill(p, tk, vs, tl):
             return forward_prefill(
                 p, cfg, tk, dest, vision=vs, impl=impl, true_len=tl,
                 qkv_sharding=qkv_sharding)
 
         if mesh is not None:
             self._prefill = jax.jit(
-                fn, in_shardings=(params_sharding, None, None, None))
+                prompt_prefill,
+                in_shardings=(params_sharding, None, None, None))
         else:
-            self._prefill = jax.jit(fn)
+            self._prefill = jax.jit(prompt_prefill)
 
     def device_cache(self):
         if not self._chunked:
@@ -302,7 +309,7 @@ class DenseCacheAdapter(KVCacheAdapter):
         cfg, max_len = self.cfg, self.sc.max_len
         self._chunk_tokens = chunk_tokens
 
-        def fn(p, tk, s, tl, slot, cache):
+        def chunk_prefill(p, tk, s, tl, slot, cache):
             return forward_prefill_chunk(
                 p, cfg, tk, DenseChunkDest(cache, slot), start=s,
                 true_len=tl, impl=impl, qkv_sharding=qkv_sharding,
@@ -310,12 +317,12 @@ class DenseCacheAdapter(KVCacheAdapter):
 
         if mesh is not None:
             self._chunk = jax.jit(
-                fn, donate_argnums=(5,),
+                chunk_prefill, donate_argnums=(5,),
                 in_shardings=(params_sharding, None, None, None, None,
                               cache_shardings),
                 out_shardings=(None, cache_shardings))
         else:
-            self._chunk = jax.jit(fn, donate_argnums=(5,))
+            self._chunk = jax.jit(chunk_prefill, donate_argnums=(5,))
 
     def admit_chunked(self, slot, tokens):
         self._lengths[slot] = 0
@@ -381,7 +388,7 @@ class PagedCacheAdapter(KVCacheAdapter):
                       cache_shardings=None, qkv_sharding=None):
         cfg = self.cfg
 
-        def fn(p, tk, tl, kp, vp, bids):
+        def prompt_prefill(p, tk, tl, kp, vp, bids):
             return forward_prefill(
                 p, cfg, tk, PagedPrefillDest(kp, vp, bids), impl=impl,
                 true_len=tl, qkv_sharding=qkv_sharding)
@@ -389,12 +396,12 @@ class PagedCacheAdapter(KVCacheAdapter):
         if mesh is not None:
             pool_k, pool_v = cache_shardings.k, cache_shardings.v
             self._prefill = jax.jit(
-                fn, donate_argnums=(3, 4),
+                prompt_prefill, donate_argnums=(3, 4),
                 in_shardings=(params_sharding, None, None, pool_k, pool_v,
                               None),
                 out_shardings=(None, (pool_k, pool_v)))
         else:
-            self._prefill = jax.jit(fn, donate_argnums=(3, 4))
+            self._prefill = jax.jit(prompt_prefill, donate_argnums=(3, 4))
 
     def device_cache(self):
         return self.pm.device_cache()
@@ -452,7 +459,7 @@ class PagedCacheAdapter(KVCacheAdapter):
                 f"block ({self.pm.bs}); got {chunk_tokens}")
         self._chunk_tokens = chunk_tokens
 
-        def fn(p, tk, s, tl, kp, vp, trow, bids):
+        def chunk_prefill(p, tk, s, tl, kp, vp, trow, bids):
             return forward_prefill_chunk(
                 p, cfg, tk, PagedChunkDest(kp, vp, trow, bids), start=s,
                 true_len=tl, impl=impl, qkv_sharding=qkv_sharding)
@@ -460,12 +467,12 @@ class PagedCacheAdapter(KVCacheAdapter):
         if mesh is not None:
             pool_k, pool_v = cache_shardings.k, cache_shardings.v
             self._chunk = jax.jit(
-                fn, donate_argnums=(4, 5),
+                chunk_prefill, donate_argnums=(4, 5),
                 in_shardings=(params_sharding, None, None, None, pool_k,
                               pool_v, None, None),
                 out_shardings=(None, (pool_k, pool_v)))
         else:
-            self._chunk = jax.jit(fn, donate_argnums=(4, 5))
+            self._chunk = jax.jit(chunk_prefill, donate_argnums=(4, 5))
 
     def admit_chunked(self, slot, tokens):
         return self.pm.admit_chunked(slot, tokens)
@@ -503,6 +510,10 @@ class PagedCacheAdapter(KVCacheAdapter):
         nbk = -(-bucket_len // self.pm.bs)
         bids = jax.ShapeDtypeStruct((nbk,), jnp.int32)
         return self._prefill.lower(pshape, tk, tl, kp, vp, bids).compile()
+
+    def span_counts(self):
+        a = self.pm.allocator
+        return {"pages_used": a.n_used, "pages_free": a.n_free}
 
     def obs_gauges(self):
         a = self.pm.allocator
@@ -567,7 +578,7 @@ class PagedQ8CacheAdapter(PagedCacheAdapter):
                       cache_shardings=None, qkv_sharding=None):
         cfg = self.cfg
 
-        def fn(p, tk, tl, kp, vp, ks, vs, bids):
+        def prompt_prefill(p, tk, tl, kp, vp, ks, vs, bids):
             return forward_prefill(
                 p, cfg, tk, PagedQ8PrefillDest(kp, vp, ks, vs, bids),
                 impl=impl, true_len=tl, qkv_sharding=qkv_sharding)
@@ -575,12 +586,13 @@ class PagedQ8CacheAdapter(PagedCacheAdapter):
         if mesh is not None:
             cs = cache_shardings
             self._prefill = jax.jit(
-                fn, donate_argnums=(3, 4, 5, 6),
+                prompt_prefill, donate_argnums=(3, 4, 5, 6),
                 in_shardings=(params_sharding, None, None, cs.k, cs.v,
                               cs.k_scale, cs.v_scale, None),
                 out_shardings=(None, (cs.k, cs.v, cs.k_scale, cs.v_scale)))
         else:
-            self._prefill = jax.jit(fn, donate_argnums=(3, 4, 5, 6))
+            self._prefill = jax.jit(prompt_prefill,
+                                    donate_argnums=(3, 4, 5, 6))
 
     def prefill(self, params, slot, padded_row, true_n, n_shared, vision):
         assert vision is None, "paged serving is attention-only (no vlm)"
@@ -606,7 +618,7 @@ class PagedQ8CacheAdapter(PagedCacheAdapter):
                 f"block ({self.pm.bs}); got {chunk_tokens}")
         self._chunk_tokens = chunk_tokens
 
-        def fn(p, tk, s, tl, kp, vp, ks, vs, trow, bids):
+        def chunk_prefill(p, tk, s, tl, kp, vp, ks, vs, trow, bids):
             return forward_prefill_chunk(
                 p, cfg, tk, PagedQ8ChunkDest(kp, vp, ks, vs, trow, bids),
                 start=s, true_len=tl, impl=impl, qkv_sharding=qkv_sharding)
@@ -614,12 +626,12 @@ class PagedQ8CacheAdapter(PagedCacheAdapter):
         if mesh is not None:
             cs = cache_shardings
             self._chunk = jax.jit(
-                fn, donate_argnums=(4, 5, 6, 7),
+                chunk_prefill, donate_argnums=(4, 5, 6, 7),
                 in_shardings=(params_sharding, None, None, None, cs.k, cs.v,
                               cs.k_scale, cs.v_scale, None, None),
                 out_shardings=(None, (cs.k, cs.v, cs.k_scale, cs.v_scale)))
         else:
-            self._chunk = jax.jit(fn, donate_argnums=(4, 5, 6, 7))
+            self._chunk = jax.jit(chunk_prefill, donate_argnums=(4, 5, 6, 7))
 
     def chunk_step(self, params, slot, chunk_row, start, true_len):
         C = self._chunk_tokens

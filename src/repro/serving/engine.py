@@ -67,7 +67,7 @@ from repro.configs.base import ModelConfig
 from repro.distribution import sharding as shd
 from repro.models import (backends, forward_step, prefill_style_key,
                           serving_style_key)
-from repro.obs import NULL, MetricsRegistry, Observer
+from repro.obs import NULL, MetricsRegistry, Observer, span
 from repro.serving import hostbufs
 from repro.serving.adapters import KVCacheAdapter, make_adapter
 
@@ -268,17 +268,17 @@ class Engine:
             # whole pool would sit on one device until a request arrives
             self.kv.update(jax.device_put(self.kv.device_cache(), csh))
 
-        def fwd(p, t, c):
+        def decode_step(p, t, c):
             return forward_step(p, self.cfg, t, c, impl=impl,
                                 qkv_sharding=qkv_sh)
 
         if mesh is not None:
             self._decode = jax.jit(
-                fwd, donate_argnums=(2,),
+                decode_step, donate_argnums=(2,),
                 in_shardings=(psh, NamedSharding(mesh, P()), csh),
                 out_shardings=(None, csh))
         else:
-            self._decode = jax.jit(fwd, donate_argnums=(2,))
+            self._decode = jax.jit(decode_step, donate_argnums=(2,))
         self.kv.build_prefill(impl, mesh=mesh, params_sharding=psh,
                               cache_shardings=csh, qkv_sharding=qkv_sh)
         # stashed for additional adapter programs (e.g. the scheduler's
@@ -494,18 +494,36 @@ class Engine:
         """One batched decode step for all active slots; returns slot->token."""
         if not self.active:
             return {}
-        t0 = self.obs.clock()  # step span includes appendability/preempts
-        self._make_appendable()
-        if not self.active:
-            return {}
-        # host_to_device copies: jax CPU zero-copies numpy buffers, and
-        # _last_token is mutated in place right after this step dispatches
-        tokens = self.host_to_device(self._last_token, np.int32)
-        logits, new_cache = self._decode(self.params, tokens,
-                                         self.kv.device_cache())
-        self.kv.update(new_cache)
-        next_tokens = np.asarray(self._sample(
-            logits, np.arange(self.sc.n_slots)))
+        with span("repro.engine.step", n_active=len(self.active),
+                  preempted=int(self._c_preempted.value),
+                  **self.kv.span_counts()):
+            t0 = self.obs.clock()  # step span includes appendability/preempts
+            with span("repro.engine.pages"):
+                self._make_appendable()
+            if not self.active:
+                return {}
+            with span("repro.engine.dispatch"):
+                # host_to_device copies: jax CPU zero-copies numpy
+                # buffers, and _last_token is mutated in place right
+                # after this step dispatches
+                tokens = self.host_to_device(self._last_token, np.int32)
+                logits, new_cache = self._decode(self.params, tokens,
+                                                 self.kv.device_cache())
+                self.kv.update(new_cache)
+            with span("repro.engine.sample"):
+                sampled = self._sample(logits, np.arange(self.sc.n_slots))
+            with span("repro.engine.sync"):  # the host waits on the chip
+                next_tokens = np.asarray(sampled)
+            with span("repro.engine.emit"):
+                emitted = self._emit(next_tokens)
+            self.obs.step_done(t0, self.obs.clock(),
+                               n_active=len(self.active),
+                               n_tokens=len(emitted))
+        return emitted
+
+    def _emit(self, next_tokens: np.ndarray) -> Dict[int, int]:
+        """Hand each active slot its sampled token; finish and release the
+        requests that are done."""
         now = time.perf_counter()
         emitted: Dict[int, int] = {}
         for slot, req in list(self.active.items()):
@@ -526,8 +544,6 @@ class Engine:
                     ttft, tok_s = _timings_of(req)
                     self.obs.request_finished(req, decode_tok_s=tok_s,
                                               ttft_s=ttft)
-        self.obs.step_done(t0, self.obs.clock(), n_active=len(self.active),
-                           n_tokens=len(emitted))
         return emitted
 
     def _make_appendable(self):
@@ -567,7 +583,6 @@ class Engine:
         Returns one :class:`RequestResult` per prompt — the generated
         token ids (list semantics preserved) plus prompt_len / new_tokens
         / ttft_s / decode_tok_s."""
-        t_gen0 = self.obs.clock()
         t_arrival = time.perf_counter()
         pending = [Request(prompt=np.asarray(p, np.int32),
                            max_new_tokens=max_new_tokens,
@@ -606,10 +621,6 @@ class Engine:
         for r in inflight:  # finished at submit time on the final pass
             if r.slot == -1:
                 results[order[id(r)]] = _result_of(r)
-        if self.obs.enabled:
-            self.obs.generate_done(
-                t_gen0, self.obs.clock(), n_requests=len(pending),
-                n_tokens=sum(r.new_tokens for r in results if r is not None))
         return results  # type: ignore
 
     # ------------------------------------------------------------------
@@ -618,14 +629,21 @@ class Engine:
         each row belongs to so temperature sampling draws from that slot's
         private PRNG stream."""
         if self.sc.temperature <= 0.0:
-            if logits.shape[-1] > self.cfg.vocab_size:  # mask padded ids
-                pad_mask = jnp.arange(logits.shape[-1]) < self.cfg.vocab_size
-                logits = jnp.where(pad_mask, logits, -1e30)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return sample_greedy(logits, vocab_size=self.cfg.vocab_size)
         sl = jnp.asarray(np.asarray(slots, np.int32))
         toks, new_keys = self._sample_rows(logits, self._slot_keys[sl])
         self._slot_keys = self._slot_keys.at[sl].set(new_keys)
         return toks
+
+
+@partial(jax.jit, static_argnames=("vocab_size",))
+def sample_greedy(logits: jnp.ndarray, *, vocab_size: int) -> jnp.ndarray:
+    """The best token of each row of ``logits``, padded vocab ids masked:
+    one program, named so in the profiler's trace."""
+    if logits.shape[-1] > vocab_size:
+        pad_mask = jnp.arange(logits.shape[-1]) < vocab_size
+        logits = jnp.where(pad_mask, logits, -1e30)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _sample_rows(logits: jnp.ndarray, keys: jnp.ndarray, *,

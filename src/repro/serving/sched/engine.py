@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import layer_plan
+from repro.obs import span
 from repro.serving.adapters import KVCacheAdapter
 from repro.serving.engine import (Engine, Request, RequestResult, ServeConfig,
                                   _result_of, _timings_of)
@@ -242,44 +243,62 @@ class ScheduledEngine(Engine):
     def step(self) -> Dict[int, int]:
         """One scheduler ITERATION: admit → plan → chunks → decode →
         activate.  Returns slot -> token for the decode portion."""
-        while self.preempted:  # re-enter the queue at the front
-            self.submit(self.preempted.pop(0))
-        t0 = self.obs.clock()
-        n_admitted = self._admit()
-        schedule = plan_iteration(self.scfg, len(self.active),
-                                  self.prefilling)
-        self.last_schedule = schedule
-        n_chunks = n_chunk_tokens = 0
-        activated = []
-        for cp in schedule.chunks:
-            tc = self.obs.clock()
-            logits = self._run_chunk(cp)
-            if logits is None:
-                continue
-            n_chunks += 1
-            n_chunk_tokens += cp.cost
-            self.obs.chunk_done(cp.job.req, cp.job.slot, cp.start,
-                                cp.end - cp.start, tc, self.obs.clock(),
-                                final=cp.final)
-            if cp.job.done:
-                act = self._finish_prefill(cp.job, logits)
-                if act is not None:
-                    activated.append(act)
-        emitted = super().step()
-        # activate AFTER the decode dispatched: this iteration's decode
-        # program shipped the shielded view, so a shared trailing partial
-        # page can't take this slot's writes before CoW sees it
-        for slot, req in activated:
-            self.active[slot] = req
-            self.kv.unshield(slot)
-        self._g_peak.set_max(len(self.active))
-        self.n_iterations += 1
-        self.n_chunks_run += n_chunks
-        self.obs.sched_iteration(t0, self.obs.clock(),
-                                 n_decode=schedule.n_decode,
-                                 n_chunks=n_chunks,
-                                 n_chunk_tokens=n_chunk_tokens,
-                                 budget_used=schedule.budget_used)
+        with span("repro.sched.iteration",
+                  waiting=len(self.waiting) + len(self.preempted),
+                  prefilling=len(self.prefilling)) as iteration:
+            with span("repro.sched.admit") as admit:
+                n_deferred = self._c_deferred.value
+                while self.preempted:  # re-enter the queue at the front
+                    self.submit(self.preempted.pop(0))
+                t0 = self.obs.clock()
+                n_admitted = self._admit()
+                admit.set_metadata(
+                    admitted=n_admitted,
+                    deferred=int(self._c_deferred.value - n_deferred))
+            with span("repro.sched.plan"):
+                schedule = plan_iteration(self.scfg, len(self.active),
+                                          self.prefilling)
+            self.last_schedule = schedule
+            n_chunks = n_chunk_tokens = 0
+            activated = []
+            for cp in schedule.chunks:
+                tc = self.obs.clock()
+                with span("repro.sched.chunk", start=cp.start,
+                          tokens=cp.end - cp.start, final=int(cp.final)):
+                    logits = self._run_chunk(cp)
+                if logits is None:
+                    continue
+                n_chunks += 1
+                n_chunk_tokens += cp.cost
+                self.obs.chunk_done(cp.job.req, cp.job.slot, cp.start,
+                                    cp.end - cp.start, tc, self.obs.clock(),
+                                    final=cp.final)
+                if cp.job.done:
+                    with span("repro.sched.first_token"):
+                        act = self._finish_prefill(cp.job, logits)
+                    if act is not None:
+                        activated.append(act)
+            emitted = super().step()
+            # activate AFTER the decode dispatched: this iteration's
+            # decode program shipped the shielded view, so a shared
+            # trailing partial page can't take this slot's writes before
+            # CoW sees it
+            with span("repro.sched.activate", activated=len(activated)):
+                for slot, req in activated:
+                    self.active[slot] = req
+                    self.kv.unshield(slot)
+            self._g_peak.set_max(len(self.active))
+            self.n_iterations += 1
+            self.n_chunks_run += n_chunks
+            self.obs.sched_iteration(t0, self.obs.clock(),
+                                     n_decode=schedule.n_decode,
+                                     n_chunks=n_chunks,
+                                     n_chunk_tokens=n_chunk_tokens,
+                                     budget_used=schedule.budget_used)
+            iteration.set_metadata(n_decode=schedule.n_decode,
+                                   n_chunks=n_chunks,
+                                   chunk_tokens=n_chunk_tokens,
+                                   budget_used=schedule.budget_used)
         self._progress = bool(emitted) or n_chunks > 0 or n_admitted > 0
         return emitted
 
@@ -291,7 +310,6 @@ class ScheduledEngine(Engine):
         (and, greedy, the same tokens) as ``Engine.generate``."""
         if vision is not None and any(v is not None for v in vision):
             raise ValueError("ScheduledEngine is attention-only (no vlm)")
-        t_gen0 = self.obs.clock()
         t_arrival = time.perf_counter()
         pending = [Request(prompt=np.asarray(p, np.int32),
                            max_new_tokens=max_new_tokens,
@@ -316,9 +334,4 @@ class ScheduledEngine(Engine):
                     results[order[id(r)]] = _result_of(r)
                     # identity removal: Request.__eq__ compares arrays
                     inflight = [x for x in inflight if x is not r]
-        if self.obs.enabled:
-            self.obs.generate_done(
-                t_gen0, self.obs.clock(), n_requests=len(pending),
-                n_tokens=sum(r.new_tokens for r in results
-                             if r is not None))
         return results  # type: ignore

@@ -9,7 +9,10 @@ ever corrupting an open span.  A hypothesis test drives the observer
 hooks with the same request-trace generator shape as
 ``test_paged_properties`` (admit / step×4 / release over a tiny slot
 set), and an engine integration test replays ``test_paged``'s
-pool-starved preempt/resume recipe with obs on.
+pool-starved preempt/resume recipe with obs on.  The program spans are
+read back from a real profiler trace of a scheduled engine: one
+iteration span per step, the engine's spans nested in its step span,
+the pool's counts as stats.
 """
 import math
 import os
@@ -421,3 +424,157 @@ def test_activated_scopes_the_active_observer():
             assert O.get_active() is O.NULL
         assert O.get_active() is obs
     assert O.get_active() is O.NULL
+
+
+@pytest.mark.parametrize("scheduled", [False, True],
+                         ids=["engine", "scheduled"])
+def test_observer_on_and_off_serve_the_same_greedy_tokens(small_model,
+                                                          scheduled):
+    """Observation must never perturb what it observes: the same prompts
+    served greedy with the observer off and on give the same tokens."""
+    from repro.serving import (Engine, PagedCacheAdapter, SchedConfig,
+                               ScheduledEngine, ServeConfig)
+    cfg, params = small_model
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, size=(int(n),))
+               .astype(np.int32) for n in rng.randint(4, 24, size=6)]
+    outs = {}
+    for on in (False, True):
+        sc = ServeConfig(n_slots=4, max_len=64, obs=on)
+        cache = PagedCacheAdapter(block_size=8, n_blocks=32)
+        eng = (ScheduledEngine(cfg, params, sc,
+                               scfg=SchedConfig(token_budget=32,
+                                                chunk_tokens=16),
+                               cache=cache) if scheduled
+               else Engine(cfg, params, sc, cache=cache))
+        outs[on] = eng.generate(prompts, max_new_tokens=8)
+        assert eng.obs.enabled is on
+    assert [list(o) for o in outs[False]] == [list(o) for o in outs[True]]
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's timeline
+# ---------------------------------------------------------------------------
+
+def _program_spans(log_dir):
+    """(name, start_ns, end_ns, stats) of every ``repro.`` host event of
+    the one trace under ``log_dir``, in time order."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    out.append((e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda sp: (sp[1], -sp[2]))
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_program_spans_nest_per_iteration_and_carry_pool_counts(
+        small_model, tmp_path):
+    """Under a profiler session every ``ScheduledEngine.step`` writes one
+    ``repro.sched.iteration``; every other span lies inside one, and the
+    ``repro.engine.*`` spans inside the iteration's ``repro.engine.step``,
+    whose ``pages_used`` is the pool's pages in use as the step begins."""
+    import jax
+    from repro.serving import (PagedCacheAdapter, SchedConfig,
+                               ScheduledEngine, ServeConfig)
+    cfg, params = small_model
+    eng = ScheduledEngine(cfg, params, ServeConfig(n_slots=3, max_len=64),
+                          scfg=SchedConfig(token_budget=32, chunk_tokens=16),
+                          cache=PagedCacheAdapter(block_size=8, n_blocks=24))
+    prompts = [np.arange(20) + i for i in range(4)]
+    eng.generate(prompts, max_new_tokens=4)  # compile outside the trace
+    used = []
+    make_appendable = eng._make_appendable
+
+    def spy():
+        used.append(eng.pm.allocator.n_used)
+        make_appendable()
+
+    eng._make_appendable = spy
+    n0 = eng.n_iterations
+    with jax.profiler.trace(str(tmp_path)):
+        outs = eng.generate(prompts, max_new_tokens=6)
+    assert all(len(o) == 6 for o in outs)
+    spans = _program_spans(str(tmp_path))
+    assert {sp[0] for sp in spans} == set(O.SPANS)
+    iters = [sp for sp in spans if sp[0] == "repro.sched.iteration"]
+    assert len(iters) == eng.n_iterations - n0
+    steps = [sp for sp in spans if sp[0] == "repro.engine.step"]
+    assert [sp[3]["pages_used"] for sp in steps] == used
+    for sp in steps:
+        assert sp[3]["pages_used"] + sp[3]["pages_free"] == 24
+        assert sp[3]["n_active"] >= 1 and sp[3]["preempted"] >= 0
+    for it in iters:
+        assert set(it[3]) == {"waiting", "prefilling", "n_decode",
+                              "n_chunks", "chunk_tokens", "budget_used"}
+        assert sum(_inside(sp, it) for sp in steps) <= 1
+    for sp in spans:
+        if sp[0] == "repro.sched.iteration":
+            continue
+        assert sum(_inside(sp, it) for it in iters) == 1, sp
+        if sp[0].startswith("repro.engine.") and sp[0] != "repro.engine.step":
+            assert sum(_inside(sp, st) for st in steps) == 1, sp
+    chunks = [sp for sp in spans if sp[0] == "repro.sched.chunk"]
+    assert sum(sp[3]["tokens"] for sp in chunks) == 4 * 20
+    assert sum(sp[3]["final"] for sp in chunks) == 4
+    admits = [sp for sp in spans if sp[0] == "repro.sched.admit"]
+    assert sum(sp[3]["admitted"] for sp in admits) == 4
+
+
+def test_program_span_table_names_each_layer_by_prefix():
+    """The benchmark matches program spans by prefix alone, so a span's
+    name says its layer; with no profiler session a span is inert."""
+    prefix = {"scheduler": "repro.sched.",
+              "engine + page pool": "repro.engine."}
+    for name, layer in O.SPANS.items():
+        assert name.startswith(prefix[layer]), (name, layer)
+    with O.span("repro.engine.step", n_active=1) as sp:
+        sp.set_metadata(pages_used=3)
+    assert not type(sp).is_enabled()
+
+
+@pytest.mark.parametrize("cache", ["dense", "paged", "paged_q8"])
+def test_serving_programs_lower_under_their_names(small_model, cache):
+    """The profiler names a program run by its module: the decode step,
+    the whole-prompt and chunk prefills and greedy sampling each lower
+    under a name of their own."""
+    import jax
+    import jax.numpy as jnp
+    from repro.serving import (PagedCacheAdapter, PagedQ8CacheAdapter,
+                               SchedConfig, ScheduledEngine, ServeConfig)
+    from repro.serving.engine import sample_greedy
+    cfg, params = small_model
+    adapter = {"dense": lambda: "dense",
+               "paged": lambda: PagedCacheAdapter(block_size=8, n_blocks=24),
+               "paged_q8": lambda: PagedQ8CacheAdapter(block_size=8,
+                                                       n_blocks=24)}[cache]
+    eng = ScheduledEngine(cfg, params, ServeConfig(n_slots=2, max_len=64),
+                          scfg=SchedConfig(token_budget=32, chunk_tokens=16),
+                          cache=adapter())
+    lowered = []
+    chunk = eng.kv._chunk
+
+    def spy(*args):
+        lowered.append(chunk.lower(*args).as_text())
+        return chunk(*args)
+
+    eng.kv._chunk = spy
+    eng.generate([np.arange(20)], max_new_tokens=2)
+    assert lowered and all("@jit_chunk_prefill" in t for t in lowered)
+    assert "HloModule jit_decode_step" in eng.compiled_decode().as_text()
+    assert "HloModule jit_prompt_prefill" in \
+        eng.compiled_prefill(16).as_text()
+    logits = jax.ShapeDtypeStruct((2, cfg.vocab_size + 3), jnp.float32)
+    assert "@jit_sample_greedy" in sample_greedy.lower(
+        logits, vocab_size=cfg.vocab_size).as_text()

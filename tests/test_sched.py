@@ -308,9 +308,9 @@ def test_scheduler_observability(setup):
 
 
 def test_null_observer_carries_sched_hooks_as_noops(setup):
-    """The zero-overhead contract extends to the new hooks: obs-off
+    """The off-mode contract extends to the scheduler's hooks: obs-off
     engines bind the module NULL singleton whose sched hooks are the
-    shared no-op (bench_obs_overhead's gate stays meaningful)."""
+    shared no-op, one attribute load and an empty call each."""
     models, prompts, _ = setup
     cfg, params = models["generic"]
     eng = _sched_engine(cfg, params, "dense")
