@@ -41,7 +41,13 @@ from jax.profiler import TraceAnnotation
 #: every program span, with the layer whose host time it covers.  The
 #: ``repro.engine.*`` spans nest inside ``repro.engine.step``, which,
 #: like every ``repro.sched.*`` span, nests inside
-#: ``repro.sched.iteration`` (one per ``ScheduledEngine.step``).
+#: ``repro.sched.iteration`` (one per ``ScheduledEngine.step``).  On a
+#: paged pool ``repro.engine.step`` carries, as the step begins, the
+#: pool's ``pages_used`` and ``pages_free`` and the decode's block-table
+#: entries: ``table_pages`` (slots x table width) and
+#: ``table_pages_live`` (mapped, shielded slots counted 0), so
+#: ``1 - live / table`` is the share of the table the paged decode
+#: kernel skips.
 SPANS = {
     "repro.sched.iteration": "scheduler",
     "repro.sched.admit": "scheduler",

@@ -512,8 +512,17 @@ class PagedCacheAdapter(KVCacheAdapter):
         return self._prefill.lower(pshape, tk, tl, kp, vp, bids).compile()
 
     def span_counts(self):
-        a = self.pm.allocator
-        return {"pages_used": a.n_used, "pages_free": a.n_free}
+        """Pages mapped and free in the pool, and the decode's block
+        tables: ``table_pages`` entries (slots x table width, the table
+        the kernel reads) of which ``table_pages_live`` are mapped,
+        shielded slots (their rows read as -1 on the device) counted 0."""
+        a, pm = self.pm.allocator, self.pm
+        live = np.count_nonzero(pm.tables >= 0)
+        for slot in pm.shielded:
+            live -= np.count_nonzero(pm.tables[slot] >= 0)
+        return {"pages_used": a.n_used, "pages_free": a.n_free,
+                "table_pages_live": int(live),
+                "table_pages": int(pm.tables.size)}
 
     def obs_gauges(self):
         a = self.pm.allocator

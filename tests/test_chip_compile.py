@@ -112,6 +112,28 @@ KERNEL_CASES = {
 }
 
 
+# the paged kernels again at the benchmark's table width: 32 slots, 128
+# table slots of 16-token pages (max_len 2,048), a pool of 3,072 pages;
+# each slot's walk gathers several pages a step by DMA
+B_SERVE, MB_SERVE, NB_SERVE = 32, 128, 3072
+
+
+def _serve_width(specs):
+    widths = {(B, HQ, D): (B_SERVE, HQ, D), (B, HQ * D): (B_SERVE, HQ * D),
+              (B, MB): (B_SERVE, MB_SERVE), (B,): (B_SERVE,),
+              (NB, PAGE, HKV, D): (NB_SERVE, PAGE, HKV, D),
+              (NB, HKV): (NB_SERVE, HKV)}
+    return [[widths[tuple(shape)], *dt] for shape, *dt in specs]
+
+
+KERNEL_CASES.update({
+    f"{name}-B{B_SERVE}-MB{MB_SERVE}-NB{NB_SERVE}":
+        (KERNEL_CASES[name][0], _serve_width(KERNEL_CASES[name][1]))
+    for name in ("decode_attention_paged", "decode_attention_paged_merged",
+                 "decode_attention_paged_q8",
+                 "decode_attention_paged_q8_merged")})
+
+
 def test_cases_cover_every_attention_kernel():
     wrappers = {fn.__name__ for fn in ops.ATTENTION_KERNELS.values()}
     assert wrappers <= set(KERNEL_CASES), wrappers - set(KERNEL_CASES)

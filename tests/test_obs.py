@@ -484,7 +484,9 @@ def test_program_spans_nest_per_iteration_and_carry_pool_counts(
     """Under a profiler session every ``ScheduledEngine.step`` writes one
     ``repro.sched.iteration``; every other span lies inside one, and the
     ``repro.engine.*`` spans inside the iteration's ``repro.engine.step``,
-    whose ``pages_used`` is the pool's pages in use as the step begins."""
+    whose ``pages_used`` is the pool's pages in use as the step begins,
+    and whose ``table_pages_live`` counts the mapped entries of the decode's
+    block tables (shielded slots' rows as -1) out of ``table_pages``."""
     import jax
     from repro.serving import (PagedCacheAdapter, SchedConfig,
                                ScheduledEngine, ServeConfig)
@@ -494,11 +496,13 @@ def test_program_spans_nest_per_iteration_and_carry_pool_counts(
                           cache=PagedCacheAdapter(block_size=8, n_blocks=24))
     prompts = [np.arange(20) + i for i in range(4)]
     eng.generate(prompts, max_new_tokens=4)  # compile outside the trace
-    used = []
+    used, live = [], []
     make_appendable = eng._make_appendable
 
     def spy():
         used.append(eng.pm.allocator.n_used)
+        tables = np.asarray(eng.kv.device_cache().block_tables)
+        live.append(int(np.count_nonzero(tables >= 0)))
         make_appendable()
 
     eng._make_appendable = spy
@@ -512,8 +516,11 @@ def test_program_spans_nest_per_iteration_and_carry_pool_counts(
     assert len(iters) == eng.n_iterations - n0
     steps = [sp for sp in spans if sp[0] == "repro.engine.step"]
     assert [sp[3]["pages_used"] for sp in steps] == used
+    assert [sp[3]["table_pages_live"] for sp in steps] == live
+    assert any(0 < n < 3 * 8 for n in live)
     for sp in steps:
         assert sp[3]["pages_used"] + sp[3]["pages_free"] == 24
+        assert sp[3]["table_pages"] == 3 * 64 // 8  # slots x table width
         assert sp[3]["n_active"] >= 1 and sp[3]["preempted"] >= 0
     for it in iters:
         assert set(it[3]) == {"waiting", "prefilling", "n_decode",

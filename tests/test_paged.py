@@ -10,6 +10,8 @@ import pytest
 
 from repro.configs import get_config, reduce_config
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import pages_per_block
+from repro.kernels.paging import paged_ring_active
 from repro.models import forward_seq, init_params
 from repro.serving import Engine, ServeConfig
 from repro.serving.paged_kv_cache import BlockAllocator, PagedCacheManager
@@ -19,34 +21,80 @@ from repro.serving.paged_kv_cache import BlockAllocator, PagedCacheManager
 # kernels vs oracles
 # ---------------------------------------------------------------------------
 
-def _rand_tables(rng, B, MB, NB, bs, lens):
+def _rand_tables(rng, B, MB, NB, bs, lens, shielded=()):
     """Distinct physical pages per slot covering each slot's current
-    position (qpos = lens[b]), rest unmapped."""
+    position (qpos = lens[b]), rest unmapped; ``shielded`` slots' rows
+    are then masked to -1, as the manager shields a mid-prefill slot."""
     bt = np.full((B, MB), -1, np.int32)
     perm = rng.permutation(NB)
     ptr = 0
     for b, L in enumerate(lens):
-        for j in range((L + bs) // bs):  # covers position L inclusive
+        for j in range(min(MB, (L + bs) // bs)):  # covers position L
             bt[b, j] = perm[ptr]
             ptr += 1
+    bt[list(shielded), :] = -1
     return jnp.asarray(bt)
 
 
-@pytest.mark.parametrize("dtype,B,Hq,Hkv,D,NB,bs,MB,win", [
-    (jnp.float32, 3, 4, 2, 64, 16, 16, 4, 0),
-    (jnp.bfloat16, 3, 4, 2, 64, 16, 16, 4, 0),
-    (jnp.float32, 2, 8, 1, 32, 12, 8, 4, 0),   # MQA
-    (jnp.float32, 2, 4, 4, 16, 14, 8, 6, 11),  # MHA + sliding window
+def _lens(rng, lens, B, MB, bs):
+    if lens is None:
+        return [int(x) for x in rng.randint(1, MB * bs - 1, size=B)]
+    assert len(lens) == B
+    return list(lens)
+
+
+# at bs 16 the kernel gathers P pages a block: lengths at the block edges
+# 1, P·bs - 1, P·bs, P·bs + 1 and MB·bs - 1, and one shielded slot (its
+# table all -1); MB = 4P walks four blocks, MB = 2.5P ends in a ragged one
+P16 = pages_per_block(16, 1 << 20)
+MB_4P, MB_RAGGED = 4 * P16, 5 * P16 // 2
+
+
+def _edges(mb, bs=16):
+    t = P16 * bs
+    return (1, t - 1, t, t + 1, mb * bs - 1, mb * bs // 2)
+
+
+EDGES_4P, EDGES_RAGGED = _edges(MB_4P), _edges(MB_RAGGED)
+NB_4P, NB_RAGGED = 3 * MB_4P, 3 * MB_RAGGED  # pages for all six slots
+SHIELD = (5,)
+
+
+def _close(out, want, dtype):
+    tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype,B,Hq,Hkv,D,NB,bs,MB,win,lens,shielded", [
+    pytest.param(jnp.float32, 3, 4, 2, 64, 16, 16, 4, 0, None, (),
+                 id="float32-3-4-2-64-16-16-4-0"),
+    pytest.param(jnp.bfloat16, 3, 4, 2, 64, 16, 16, 4, 0, None, (),
+                 id="bfloat16-3-4-2-64-16-16-4-0"),
+    pytest.param(jnp.float32, 2, 8, 1, 32, 12, 8, 4, 0, None, (),  # MQA
+                 id="float32-2-8-1-32-12-8-4-0"),
+    pytest.param(jnp.float32, 2, 4, 4, 16, 14, 8, 6, 11, None, (),
+                 id="float32-2-4-4-16-14-8-6-11"),  # MHA + sliding window
+    pytest.param(jnp.float32, 6, 8, 2, 32, NB_4P, 16, MB_4P, 0, EDGES_4P,
+                 SHIELD, id="block-edges-MB4P-float32"),
+    pytest.param(jnp.bfloat16, 6, 8, 2, 32, NB_4P, 16, MB_4P, 0, EDGES_4P,
+                 SHIELD, id="block-edges-MB4P-bfloat16"),
+    pytest.param(jnp.float32, 6, 8, 2, 32, NB_RAGGED, 16, MB_RAGGED, 0,
+                 EDGES_RAGGED, SHIELD, id="block-edges-ragged"),
+    pytest.param(jnp.float32, 6, 8, 2, 32, NB_4P, 16, MB_4P, 100, EDGES_4P,
+                 (), id="block-edges-MB4P-window100"),
 ])
-def test_paged_decode_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win):
+def test_paged_decode_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win,
+                                         lens, shielded):
     rng = np.random.RandomState(0)
     G = Hq // Hkv
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
     kp = jax.random.normal(ks[1], (NB, bs, Hkv, D), dtype)
     vp = jax.random.normal(ks[2], (NB, bs, Hkv, D), dtype)
-    lens = [int(x) for x in rng.randint(1, MB * bs - 1, size=B)]
-    bt = _rand_tables(rng, B, MB, NB, bs, lens)
+    lens = _lens(rng, lens, B, MB, bs)
+    bt = _rand_tables(rng, B, MB, NB, bs, lens, shielded)
     qpos = jnp.asarray(lens, jnp.int32)
     out = ops.decode_attention_paged(q, kp, vp, block_tables=bt,
                                      q_position=qpos, sliding_window=win,
@@ -54,35 +102,135 @@ def test_paged_decode_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win):
     want = ref.ref_decode_attention_paged(
         q.reshape(B, Hkv, G, D), kp, vp, bt, qpos,
         sliding_window=win).reshape(B, Hq, D)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=tol, rtol=tol)
+    _close(out, want, dtype)
+    for b in shielded:  # no live page: zeros, as the kernel always wrote
+        assert not np.any(np.asarray(out[b], np.float32))
 
 
-@pytest.mark.parametrize("dtype,B,Hq,Hkv,D,NB,bs,MB,win", [
-    (jnp.float32, 3, 4, 2, 64, 16, 16, 4, 0),
-    (jnp.bfloat16, 2, 4, 1, 32, 12, 8, 4, 0),  # MQA
-    (jnp.float32, 2, 4, 4, 16, 14, 8, 6, 11),  # MHA + sliding window
+@pytest.mark.parametrize("dtype,B,Hq,Hkv,D,NB,bs,MB,win,lens,shielded", [
+    pytest.param(jnp.float32, 3, 4, 2, 64, 16, 16, 4, 0, None, (),
+                 id="float32-3-4-2-64-16-16-4-0"),
+    pytest.param(jnp.bfloat16, 2, 4, 1, 32, 12, 8, 4, 0, None, (),  # MQA
+                 id="bfloat16-2-4-1-32-12-8-4-0"),
+    pytest.param(jnp.float32, 2, 4, 4, 16, 14, 8, 6, 11, None, (),
+                 id="float32-2-4-4-16-14-8-6-11"),  # MHA + sliding window
+    pytest.param(jnp.bfloat16, 6, 8, 2, 32, NB_4P, 16, MB_4P, 0, EDGES_4P,
+                 SHIELD, id="block-edges-MB4P-bfloat16"),
+    pytest.param(jnp.float32, 6, 8, 2, 32, NB_RAGGED, 16, MB_RAGGED, 0,
+                 EDGES_RAGGED, SHIELD, id="block-edges-ragged"),
 ])
-def test_paged_merged_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win):
+def test_paged_merged_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win,
+                                         lens, shielded):
     rng = np.random.RandomState(1)
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     u = jax.random.normal(ks[0], (B, Hq * D), dtype)
     kp = jax.random.normal(ks[1], (NB, bs, Hkv, D), dtype)
     vp = jax.random.normal(ks[2], (NB, bs, Hkv, D), dtype)
-    lens = [int(x) for x in rng.randint(1, MB * bs - 1, size=B)]
-    bt = _rand_tables(rng, B, MB, NB, bs, lens)
+    lens = _lens(rng, lens, B, MB, bs)
+    bt = _rand_tables(rng, B, MB, NB, bs, lens, shielded)
     qpos = jnp.asarray(lens, jnp.int32)
     out = ops.decode_attention_paged_merged(
         u, kp, vp, block_tables=bt, q_position=qpos, n_kv_heads=Hkv,
         sliding_window=win, interpret=True)
     want = ref.ref_decode_attention_paged_merged(
         u, kp, vp, bt, qpos, n_kv_heads=Hkv, sliding_window=win)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=tol, rtol=tol)
+    _close(out, want, dtype)
+
+
+def _q8_pool(key, NB, bs, Hkv, D):
+    kq, ks = jax.random.split(key)
+    pool = jax.random.randint(kq, (NB, bs, Hkv, D), -127, 128,
+                              jnp.int32).astype(jnp.int8)
+    return pool, jax.random.uniform(ks, (NB, Hkv), jnp.float32, 0.01, 0.05)
+
+
+def _ring_tables(rng, B, R, NB, bs, lens):
+    """Ring-addressed tables: absolute block b of a slot lives at table
+    slot b % R, the last R blocks up to the query's mapped."""
+    bt = np.full((B, R), -1, np.int32)
+    perm = rng.permutation(NB)
+    ptr = 0
+    for b, L in enumerate(lens):
+        for blk in range(max(0, L // bs - R + 1), L // bs + 1):
+            bt[b, blk % R] = perm[ptr]
+            ptr += 1
+    return jnp.asarray(bt)
+
+
+@pytest.mark.parametrize("style", ["generic", "merged"])
+@pytest.mark.parametrize("table", ["block-edges", "ring"])
+def test_paged_q8_kernel_matches_ref(style, table):
+    """The int8 wrappers at the block edges (MB = 4P, one shielded slot)
+    and on a ring-addressed table (window 400 < MB·bs: R = 26 slots, a
+    full block and a ragged one), against the dequantize-then-attend
+    oracle."""
+    rng = np.random.RandomState(3)
+    B, Hq, Hkv, D, NB, bs = 6, 8, 2, 32, NB_4P, 16
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(k1, (B, Hq, D), jnp.float32)
+    (kp, ksc), (vp, vsc) = _q8_pool(k2, NB, bs, Hkv, D), \
+        _q8_pool(k3, NB, bs, Hkv, D)
+    if table == "ring":
+        win, lens = 400, [1, 255, 257, 420, 1500, 3000]
+        R = paged_ring_active(win, bs, 26)
+        assert R == 26 and R % P16
+        bt = _ring_tables(rng, B, R, NB, bs, lens)
+    else:
+        win, lens, R = 0, list(EDGES_4P), 0
+        bt = _rand_tables(rng, B, MB_4P, NB, bs, lens, SHIELD)
+    qpos = jnp.asarray(lens, jnp.int32)
+    if style == "merged":
+        out = ops.decode_attention_paged_q8_merged(
+            q.reshape(B, Hq * D), kp, vp, k_scale=ksc, v_scale=vsc,
+            block_tables=bt, q_position=qpos, n_kv_heads=Hkv,
+            sliding_window=win, interpret=True).reshape(B, Hq, D)
+    else:
+        out = ops.decode_attention_paged_q8(
+            q, kp, vp, k_scale=ksc, v_scale=vsc, block_tables=bt,
+            q_position=qpos, sliding_window=win, interpret=True)
+    want = ref.ref_decode_attention_paged_q8(
+        q.reshape(B, Hkv, Hq // Hkv, D), kp, vp, ksc, vsc, bt, qpos,
+        sliding_window=win, ring_blocks=R).reshape(B, Hq, D)
+    _close(out, want, jnp.float32)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "q8"])
+def test_paged_kernel_never_reads_unmapped_pages(pool):
+    """Every pool page no table maps holds NaN (its scales too, for an
+    int8 pool): the output is finite and equals the oracle's on the clean
+    pool, so a dead page never reaches the result."""
+    rng = np.random.RandomState(4)
+    B, Hq, Hkv, D, NB, bs, MB = 6, 8, 2, 32, NB_4P, 16, MB_4P
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(k1, (B, Hq, D), jnp.float32)
+    bt = _rand_tables(rng, B, MB, NB, bs, EDGES_4P, SHIELD)
+    qpos = jnp.asarray(EDGES_4P, jnp.int32)
+    dead = np.ones(NB, bool)
+    dead[np.asarray(bt)[np.asarray(bt) >= 0]] = False
+    dead = jnp.asarray(dead)
+    if pool == "q8":
+        (kp, ksc), (vp, vsc) = _q8_pool(k2, NB, bs, Hkv, D), \
+            _q8_pool(k3, NB, bs, Hkv, D)
+        nan_s = lambda s: jnp.where(dead[:, None], jnp.nan, s)  # noqa: E731
+        out = ops.decode_attention_paged_q8(
+            q, kp, vp, k_scale=nan_s(ksc), v_scale=nan_s(vsc),
+            block_tables=bt, q_position=qpos, interpret=True)
+        want = ref.ref_decode_attention_paged_q8(
+            q.reshape(B, Hkv, Hq // Hkv, D), kp, vp, ksc, vsc, bt, qpos)
+    else:
+        kp = jax.random.normal(k2, (NB, bs, Hkv, D), jnp.bfloat16)
+        vp = jax.random.normal(k3, (NB, bs, Hkv, D), jnp.bfloat16)
+        nan_p = lambda p: jnp.where(  # noqa: E731
+            dead[:, None, None, None], jnp.nan, p).astype(p.dtype)
+        out = ops.decode_attention_paged(
+            q.astype(jnp.bfloat16), nan_p(kp), nan_p(vp), block_tables=bt,
+            q_position=qpos, interpret=True)
+        want = ref.ref_decode_attention_paged(
+            q.astype(jnp.bfloat16).reshape(B, Hkv, Hq // Hkv, D), kp, vp,
+            bt, qpos)
+    assert np.all(np.isfinite(np.asarray(out, np.float32)))
+    _close(out, want.reshape(B, Hq, D),
+           jnp.bfloat16 if pool == "bf16" else jnp.float32)
 
 
 def test_paged_ref_matches_dense_ref():
