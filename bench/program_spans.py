@@ -5,10 +5,11 @@ The serving program writes spans on the profiler's host timeline, on the
 device's clock (``repro.sched.*`` for the scheduler, ``repro.engine.*``
 for the engine and its page pool), each with host integers as stats
 (``repro.engine.step`` carries the pool's ``pages_used`` and
-``pages_free``).  This module reads them from an ``.xplane.pb`` and does
-the arithmetic on plain tuples, so it is checked on hand-made intervals
-and on a recorded trace alike (``bench/tests/test_bench_program_spans
-.py``).  It matches spans by prefix and imports nothing of the program.
+``pages_free``).  ``bench.trace.load`` keeps them from an ``.xplane.pb``
+as ``Trace.program``, and this module does the arithmetic on those plain
+tuples, so it is checked on hand-made intervals and on a recorded trace
+alike (``bench/tests/test_bench_program_spans.py``).  It matches spans
+by prefix and imports nothing of the program.
 
 Each idle gap of the device (``bench.trace.idle_gaps``) is split by the
 innermost program span open at each instant: under a scheduler span, an
@@ -33,29 +34,13 @@ if __package__ in (None, ""):
 
 from bench import trace as T  # noqa: E402
 
-#: (name, start_s, end_s, stats)
-ProgramEvent = Tuple[str, float, float, Dict[str, int]]
+ProgramEvent = T.ProgramEvent
 Segment = Tuple[float, float, Optional[str]]  # innermost span, or None
 
 LAYERS = (("repro.sched.", "scheduler"),
           ("repro.engine.", "engine + page pool"))
 HARNESS = "harness"
 POOL_SPAN = "repro.engine.step"
-
-
-def load(path: str) -> List[ProgramEvent]:
-    """The program's spans on the host planes, in time order."""
-    from jax.profiler import ProfileData
-    out: List[ProgramEvent] = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if layer_of(e.name) is not None:
-                    out.append((e.name, e.start_ns * 1e-9, e.end_ns * 1e-9,
-                                {k: v for k, v in e.stats}))
-    return sorted(out, key=lambda ev: (ev[1], -ev[2]))
 
 
 def layer_of(name: str) -> Optional[str]:
@@ -132,8 +117,8 @@ def pool_pages_mapped(program: Sequence[ProgramEvent], lo: float,
     return max(shares) if shares else None
 
 
-def summary(tr: T.Trace, program: Sequence[ProgramEvent]) -> Dict:
-    ops = tr.ops[0]
+def summary(tr: T.Trace) -> Dict:
+    ops, program = tr.ops[0], tr.program
     if any(n == T.SPAN_PREFIX + "window" for n, _, _ in tr.spans):
         lo, hi = T.window_of(tr, T.SPAN_PREFIX + "window")
     else:
@@ -165,7 +150,7 @@ def main(argv=None) -> int:
     if not tr.ops:
         print(f"no device operations in {path}", file=sys.stderr)
         return 1
-    print(json.dumps(summary(tr, load(path))))
+    print(json.dumps(summary(tr)))
     return 0
 
 

@@ -1,7 +1,8 @@
 """Plain float32 reference of the Mistral block family, in ``jax.numpy``.
 
 Two block styles, written from their published equations and nothing of
-the program (weights come from ``bench.weights`` and the seed):
+the program (weights come from ``bench.weights`` and the seed, as the
+block family's leaves say):
 
   standard         Mistral-7B (arXiv:2310.06825): pre-norm residual block,
                    RMSNorm, RoPE ("half" layout), grouped-query attention
@@ -19,7 +20,7 @@ scan that draws each layer's weights from the seed inside the step), in
 float32 at ``Precision.HIGHEST``, with attention and the unembedding in
 blocks of query rows, so that it fits beside nothing else on the chip.
 
-``weights="fp8"`` is the control: every weight rounded to float8 e4m3
+``mode="fp8"`` is the control: every weight rounded to float8 e4m3
 with one scale per output channel, the step below the configuration's
 bfloat16 that a later change might be tempted to take.
 """
@@ -40,9 +41,10 @@ FP8_MAX = 448.0
 
 
 def _fp8_round(w):
-    """Round a matrix to float8 e4m3 with one scale per output column
-    (per row for an embedding table), back in float32."""
-    amax = jnp.max(jnp.abs(w), axis=0, keepdims=True)
+    """Round a matrix (or a stack of them) to float8 e4m3 with one scale
+    per output column (per row for an embedding table), back in
+    float32."""
+    amax = jnp.max(jnp.abs(w), axis=-2, keepdims=True)
     scale = jnp.where(amax > 0, amax / FP8_MAX, 1.0)
     q = (w / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32)
     return q * scale
@@ -60,7 +62,7 @@ def _weight(seed_k, path, layer, shape, std, served, mode):
     return w
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale
 
@@ -107,13 +109,14 @@ def _attention(q, k, v, pos, seg, window):
     return out.transpose(1, 0, 2, 3).reshape(R, S, H * D)
 
 
-def _block(cfg, lw, h, pos, seg):
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
+def attend(cfg, lw, x, pos, seg):
+    """One layer's attention over the normed stream ``x`` (R, S, d): Q (or
+    the stream itself), K and V, RoPE, windowed GQA and P."""
+    theta = cfg["rope_theta"]
     window = cfg.get("sliding_window") or 0
-    R, S, d = h.shape
+    R, S, d = x.shape
     H, Hk = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     D = d // H
-    x = _rms(h, lw["norm1/scale"], eps)
     if cfg["block_style"] == "standard":
         q = jnp.dot(x, lw["attn/wq"], precision=HI)
     else:  # residual_qpfree: the normed stream is the query
@@ -125,23 +128,38 @@ def _block(cfg, lw, h, pos, seg):
     a = _attention(q, k, v, pos, seg, window)
     if cfg["block_style"] == "standard":
         a = jnp.dot(a, lw["attn/wp"], precision=HI)
-    h = h + a
-    x = _rms(h, lw["norm2/scale"], eps)
+    return a
+
+
+def _block(cfg, lw, h, pos, seg):
+    eps = cfg["rms_norm_eps"]
+    h = h + attend(cfg, lw, rms(h, lw["norm1/scale"], eps), pos, seg)
+    x = rms(h, lw["norm2/scale"], eps)
     g = jnp.dot(x, lw["ffn/w_gate"], precision=HI)
     u = jnp.dot(x, lw["ffn/w_up"], precision=HI)
     return h + jnp.dot(jax.nn.silu(g) * u, lw["ffn/w_down"], precision=HI)
 
 
-def make_forward(cfg: Dict, mode: str = "served"):
+def make_forward(cfg: Dict, family, mode: str = "served"):
     """Jitted ``f(seed_key, tokens, positions, segs, targets)`` over packed
     (R, S) rows -> (best logit, logit of ``targets``, argmax id), each
     (R, S) at every position.  ``mode`` "served": the served
-    (bfloat16) weights in float32 arithmetic; "fp8": the control."""
+    (bfloat16) weights in float32 arithmetic; "fp8": the control.
+    ``family``: the configuration's block family, whose leaves the
+    weights are drawn as."""
+    return forward(cfg, family, _block, mode)
+
+
+def forward(cfg: Dict, family, block, mode: str = "served"):
+    """``make_forward`` with the layer ``block(cfg, layer weights by path,
+    h, positions, segs) -> h`` of another family: the embedding, the
+    layers drawn one by one, the final RMSNorm and the blocked
+    unembedding stay as they are."""
     served = jnp.dtype(cfg["torch_dtype"])
     n_layers = cfg["num_hidden_layers"]
     vocab = cfg["vocab_size"]
-    leaves = W.layer_leaves(cfg)
-    top = {p: (s, sd) for p, s, sd in W.top_leaves(cfg)}
+    leaves = family.layer_leaves(cfg)
+    top = {p: (s, sd) for p, s, sd in family.top_leaves(cfg)}
 
     def fwd(seed_k, tokens, pos, seg, targets):
         tw = functools.partial(_weight, seed_k, layer=None, served=served,
@@ -153,11 +171,11 @@ def make_forward(cfg: Dict, mode: str = "served"):
         def layer(hh, i):
             lw = {p: _weight(seed_k, p, i, s, sd, served, mode)
                   for p, s, sd in leaves}
-            return _block(cfg, lw, hh, pos, seg), None
+            return block(cfg, lw, hh, pos, seg), None
 
         h, _ = jax.lax.scan(layer, h, jnp.arange(n_layers))
-        h = _rms(h, tw("final_norm/scale", shape=top["final_norm/scale"][0],
-                       std=0.0), cfg["rms_norm_eps"])
+        h = rms(h, tw("final_norm/scale", shape=top["final_norm/scale"][0],
+                      std=0.0), cfg["rms_norm_eps"])
         un = tw("unembed/table", shape=top["unembed/table"][0],
                 std=top["unembed/table"][1])
         R, S, d = h.shape

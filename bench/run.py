@@ -5,7 +5,8 @@
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration file
 and a traffic mix; everything else is found by those names (``bench/
-spec.py``).  One run:
+spec.py``): the block family, by the file's ``model_type``, gives the
+program's configuration, the weights' leaves and the counts.  One run:
 
   set-up   weights made on the chip from ``--seed`` in one jitted call;
            ``ScheduledEngine`` over the paged pool with ``impl="pallas"``
@@ -21,7 +22,8 @@ spec.py``).  One run:
            limit.
   trace    with ``--trace 1``: the per-layer metrics (``bench/metrics``)
            instead of the end-to-end ones, from a profiler trace of part
-           of the window and the harness's own records.
+           of the window (the device's ops, the harness's spans and the
+           program's) and the harness's own records.
 
 Exits non-zero, printing no result, when JAX finds no TPU or fewer chips
 than the cell asks for, or when the program is not beside the benchmark.
@@ -151,7 +153,6 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
     from repro.serving import Request
 
     from bench import weights
-    from bench.model import program_config
     from bench.traffic import Mix
 
     t_start = T_START if t_start is None else t_start
@@ -173,8 +174,9 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
 
     # -- set-up --------------------------------------------------------------
     marks = [("start", time.perf_counter())]
-    mc = program_config(cfg)
-    params = weights.make_params(cfg, seed, jax.numpy.dtype(
+    family = spec.family(cfg)
+    mc = family.program_config(cfg)
+    params = weights.make_params(family, cfg, seed, jax.numpy.dtype(
         cfg["torch_dtype"]))
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
@@ -243,7 +245,7 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
     ref = spec.reference(cfg)
     ref_cfg = reference_config(cfg)
     key = weights.seed_key(seed)
-    fwd = ref.make_forward(ref_cfg)
+    fwd = ref.make_forward(ref_cfg, family)
     rows = check.pack(placed, rows_n, width)
     got = check.served_gaps(fwd, key, rows, vocab)
     gaps = got["gaps"]
@@ -265,16 +267,17 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
         f"tokens) against the float32 reference; gaps: {gap_stats(gaps)}")
     ctl = None
     if control:
-        cg = check.control_gaps(fwd, ref.make_forward(ref_cfg, "fp8"), key,
-                                rows)
+        cg = check.control_gaps(
+            fwd, ref.make_forward(ref_cfg, family, "fp8"), key, rows)
         ctl = dict(compared, worst_logit_gap={
             "value": float(cg.max()) if len(cg) else float("inf"),
             "limit": limit})
         say(f"control (reference in float8) gaps: {gap_stats(cg)}")
     wit = None
     if witness:
-        wg = check.served_gaps(ref.make_forward(dict(ref_cfg, **witness)),
-                               key, rows, vocab)["gaps"]
+        wg = check.served_gaps(
+            ref.make_forward(dict(ref_cfg, **witness), family), key, rows,
+            vocab)["gaps"]
         wit = float(wg.max()) if len(wg) else float("inf")
         say(f"witness {witness} gaps: {gap_stats(wg)}")
     say(f"check s: {time.perf_counter() - t_check:.2f}")
@@ -314,7 +317,8 @@ def run_cell(spec: Spec, workload: str, seed: int, seconds: float,
         device["window_s"] = hi - lo
         ctx = SimpleNamespace(
             cfg=cfg, mix=mix_spec, peak=peak, result=res, trace=tr,
-            traced=(lo, hi), busy_s=busy, flops=flops, serve=serve,
+            traced=(lo, hi), busy_s=busy, family=family, flops=flops,
+            serve=serve,
             traced_steps=[s for s in res.steps
                           if traced["lo"] <= s.t0 and s.t1 <= traced["hi"]])
         for m in spec.per_layer(workload):

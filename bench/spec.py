@@ -2,9 +2,11 @@
 
 A configuration is ``configs/<name>.json`` (its ``file`` in
 ``BENCHMARK.json``), a traffic mix ``traffic/<name>.json``, a per-layer
-metric ``metrics/<name>.py`` with a ``read(ctx)`` function, and a
-configuration's reference ``references/<reference>.py``.  Adding any of
-them is adding a file and an entry; nothing here changes.
+metric ``metrics/<name>.py`` with a ``read(ctx)`` function, a
+configuration's block family ``families/<model_type>.py`` (by the
+published ``model_type`` its file states) and its reference
+``references/<reference>.py``.  Adding any of them is adding a file and
+an entry; nothing here changes.
 """
 from __future__ import annotations
 
@@ -40,6 +42,13 @@ class Spec:
         path = self.bench / "references" / f"{cfg['reference']}.py"
         return _load(path, f"bench_reference_{cfg['reference']}")
 
+    def family(self, cfg: Dict):
+        """The module of the configuration's block family: its
+        ``program_config``, ``layer_leaves``, ``top_leaves`` and counts
+        (``bench.flops.COUNTS``)."""
+        path = self.bench / "families" / f"{cfg['model_type']}.py"
+        return _load(path, "bench_family_" + _ident(cfg["model_type"]))
+
     def end_to_end(self, workload: str) -> List[Dict]:
         return [m for m in self.doc["end_to_end"]
                 if workload in m.get("workloads", [workload])]
@@ -59,9 +68,7 @@ class Spec:
 
     def reader(self, metric: str) -> Callable:
         path = self.bench / "metrics" / f"{metric}.py"
-        mod = _load(path, "bench_metric_" + metric.replace(".", "_")
-                    .replace("-", "_"))
-        return mod.read
+        return _load(path, "bench_metric_" + _ident(metric)).read
 
 
 def _named(entries: List[Dict], name: str, what: str) -> Dict:
@@ -69,6 +76,10 @@ def _named(entries: List[Dict], name: str, what: str) -> Dict:
     if len(hits) != 1:
         raise KeyError(f"no {what} named {name!r} in BENCHMARK.json")
     return hits[0]
+
+
+def _ident(name: str) -> str:
+    return name.replace(".", "_").replace("-", "_")
 
 
 def _load(path: Path, modname: str):

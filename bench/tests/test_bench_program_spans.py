@@ -90,7 +90,7 @@ def test_a_trace_without_program_spans_reads_none():
     """The trace recorded before the program wrote spans: no value."""
     tr = T.load(str(OLD))
     lo, hi = T.window_of(tr, "bench.window")
-    program = PS.load(str(OLD))
+    program = tr.program
     assert program == []
     assert PS.idle_share(tr.ops[0], program, lo, hi, SCHED) is None
     assert PS.pool_pages_mapped(program, lo, hi) is None
@@ -112,7 +112,7 @@ def test_recorded_trace_with_program_spans():
     mods = T.sum_by_name(T.clip(tr.modules[0], lo, hi))
     assert {"jit_decode_step", "jit_sample_greedy"} <= set(mods)
     assert "jit_fwd" not in mods
-    program = PS.load(str(RECORDED))
+    program = tr.program
     assert program
     steps = [sp for sp in tr.spans
              if sp[0] == "bench.step" and lo <= sp[1] and sp[2] <= hi]
@@ -146,3 +146,59 @@ def test_cli_prints_the_summary(capsys):
         pytest.approx(out["idle_s"])
     assert max(out["idle_s_by_span"], key=out["idle_s_by_span"].get) == \
         "repro.engine.sync"
+
+
+READERS = ("device_idle_sched.decode", "device_idle_engine.decode",
+           "pool_pages_mapped.decode", "table_pages_live.decode")
+
+
+def read_all(tr, lo, hi):
+    """Each program-span metric as ``bench/run.py`` reads it: its reader
+    file, given the trace as ``bench.trace.load`` keeps it."""
+    from types import SimpleNamespace
+
+    from bench.spec import Spec
+    ctx = SimpleNamespace(trace=tr, traced=(lo, hi),
+                          busy_s=T.busy_seconds(tr.ops[0], lo, hi))
+    names = READERS + ("device_idle.decode",)
+    return {m: Spec().reader(m)(ctx) for m in names}
+
+
+def test_readers_on_the_recorded_trace():
+    """The trace recorded with the program's spans (before the engine
+    counted its block tables): the scheduler's and the engine's part of
+    the idle time, within the device's, and the pool's share mapped."""
+    tr = T.load(str(RECORDED))
+    lo, hi = T.window_of(tr, "bench.window")
+    got = read_all(tr, lo, hi)
+    assert got["device_idle_sched.decode"] == pytest.approx(0.321413,
+                                                            abs=1e-6)
+    assert got["device_idle_engine.decode"] == pytest.approx(9.388704,
+                                                             abs=1e-6)
+    assert got["device_idle_sched.decode"] + \
+        got["device_idle_engine.decode"] <= got["device_idle.decode"]
+    assert got["pool_pages_mapped.decode"] == pytest.approx(55.045573,
+                                                            abs=1e-5)
+    assert got["table_pages_live.decode"] is None
+
+
+def test_readers_read_nothing_without_program_spans():
+    tr = T.load(str(OLD))
+    lo, hi = T.window_of(tr, "bench.window")
+    got = read_all(tr, lo, hi)
+    assert all(got[m] is None for m in READERS)
+    assert got["device_idle.decode"] > 0
+
+
+def test_table_share_sums_the_steps_begun_in_the_window():
+    def step(t, live, table):
+        return ("repro.engine.step", t, t + 0.5,
+                {"pages_used": live, "pages_free": 200 - live,
+                 "table_pages_live": live, "table_pages": table})
+
+    program = [step(1.0, 30, 100), ("repro.engine.sync", 1.1, 1.4, {}),
+               step(3.0, 50, 100), step(9.0, 100, 100)]
+    tr = T.Trace(ops=[OPS], modules=[[]], spans=[], program=program)
+    got = read_all(tr, 0.0, 8.0)
+    assert got["table_pages_live.decode"] == pytest.approx(40.0)
+    assert got["pool_pages_mapped.decode"] == pytest.approx(25.0)

@@ -13,15 +13,16 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import tiny, weights  # noqa: E402
-from bench.model import program_config  # noqa: E402
 from bench.references.mistral import make_forward  # noqa: E402
+from bench.spec import Spec  # noqa: E402
 
 SEED = 2 ** 33 + 5
+FAMILY = Spec().family({"model_type": "mistral"})
 
 
 def tiny_cfg(style, window=0):
-    c = dict(name="tiny", rope_theta=10000.0, rms_norm_eps=1e-6,
-             sliding_window=window, hidden_act="silu",
+    c = dict(name="tiny", model_type="mistral", rope_theta=10000.0,
+             rms_norm_eps=1e-6, sliding_window=window, hidden_act="silu",
              tie_word_embeddings=False, torch_dtype="float32",
              block_style=style)
     c.update(tiny.CONFIG)
@@ -33,8 +34,8 @@ def tiny_cfg(style, window=0):
 def test_reference_matches_program_forward(style, window):
     from repro.models import forward_seq, init_params
     cfg = tiny_cfg(style, window)
-    mc = program_config(cfg)
-    p = weights.make_params(cfg, SEED, jnp.float32)
+    mc = FAMILY.program_config(cfg)
+    p = weights.make_params(FAMILY, cfg, SEED, jnp.float32)
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), mc))
     assert jax.tree.structure(shapes) == jax.tree.structure(p)
     S = 32
@@ -42,7 +43,7 @@ def test_reference_matches_program_forward(style, window):
     with jax.default_matmul_precision("highest"):
         lg = np.asarray(forward_seq(p, mc, toks)[0])
     tg = jnp.roll(toks, -1, axis=1)
-    best, got, arg = (np.asarray(x) for x in make_forward(cfg)(
+    best, got, arg = (np.asarray(x) for x in make_forward(cfg, FAMILY)(
         weights.seed_key(SEED), toks, jnp.arange(S)[None],
         jnp.zeros((1, S), jnp.int32), tg))
     scale = np.abs(lg).max()
@@ -54,7 +55,7 @@ def test_reference_matches_program_forward(style, window):
 
 def test_packed_rows_equal_separate_rows():
     cfg = tiny_cfg("standard")
-    f = make_forward(cfg)
+    f = make_forward(cfg, FAMILY)
     key = weights.seed_key(SEED)
     toks = jax.random.randint(jax.random.PRNGKey(2), (1, 64), 0, 128)
     seg = jnp.repeat(jnp.arange(2), 32)[None]
@@ -72,9 +73,9 @@ def test_packed_rows_equal_separate_rows():
 
 def test_layer_regenerated_alone_equals_stacked_leaf():
     cfg = tiny_cfg("standard")
-    p = weights.make_params(cfg, SEED, jnp.bfloat16)
+    p = weights.make_params(FAMILY, cfg, SEED, jnp.bfloat16)
     key = weights.seed_key(SEED)
-    for path, shape, std in weights.layer_leaves(cfg):
+    for path, shape, std in FAMILY.layer_leaves(cfg):
         a, b = path.split("/")
         w1 = weights.layer_leaf(key, path, 1, shape, std, jnp.bfloat16)
         assert (np.asarray(p["layers"][a][b][1]) == np.asarray(w1)).all()

@@ -10,6 +10,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
+from bench.flops import COUNTS  # noqa: E402
 from bench.spec import Spec  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -66,8 +67,14 @@ def test_committed_benchmark_resolves_and_keeps_its_contract():
         assert NAME.match(wl["name"]) and len(wl["why"]) <= 200
         cfg = spec.config(wl["config"])
         assert spec.reference(cfg).make_forward
+        family = spec.family(cfg)
+        for name in ("program_config", "layer_leaves", "top_leaves") \
+                + COUNTS:
+            assert callable(getattr(family, name)), name
         mix = spec.traffic(wl["traffic"])
-        assert mix["engine"]["max_len"] <= cfg["sliding_window"]
+        # the window never binds: pages stay absolutely addressed
+        if cfg.get("sliding_window"):
+            assert mix["engine"]["max_len"] <= cfg["sliding_window"]
         reported = [m["name"] for m in spec.end_to_end(wl["name"])]
         assert "setup_s" in reported and len(reported) >= 2
         layer = spec.per_layer(wl["name"])
@@ -83,6 +90,7 @@ def test_committed_benchmark_resolves_and_keeps_its_contract():
     layers = {}
     for m in doc["per_layer"]:
         assert NAME.match(m["name"])
+        assert callable(spec.reader(m["name"]))
         if m["name"].endswith("_roofline") or "mfu" in m["name"]:
             assert m["unit"] == "%"
         layers.setdefault(m["layer"], m["layer"])

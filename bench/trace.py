@@ -14,7 +14,9 @@ kernel by its ``name=``); ``load`` keeps the instruction name before
 events of their own.  The ``XLA Modules`` line holds one event per
 program run (``jit_fwd(<id>)``, kept as ``jit_fwd``).  The harness's own
 spans (``jax.profiler.TraceAnnotation``, named ``bench.*``) are on the
-host plane, on the same clock.
+host plane, on the same clock, and so are the program's (``repro.*``,
+each with its stats: host integers such as the pool's pages), which
+``bench/program_spans.py`` reads.
 """
 from __future__ import annotations
 
@@ -25,11 +27,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]  # (start_s, end_s)
 Event = Tuple[str, float, float]  # (name, start_s, end_s)
+#: (name, start_s, end_s, stats)
+ProgramEvent = Tuple[str, float, float, Dict[str, int]]
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PREFIX = "/device:"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 
 
 @dataclass
@@ -37,6 +42,8 @@ class Trace:
     ops: List[List[Event]] = field(default_factory=list)  # per device
     modules: List[List[Event]] = field(default_factory=list)  # per device
     spans: List[Event] = field(default_factory=list)  # harness spans
+    # program spans, in time order (outer before inner)
+    program: List[ProgramEvent] = field(default_factory=list)
 
 
 def find_xplane(log_dir: str) -> str:
@@ -72,6 +79,11 @@ def load(path: str) -> Trace:
                     if e.name.startswith(SPAN_PREFIX):
                         tr.spans.append((e.name, e.start_ns * 1e-9,
                                          e.end_ns * 1e-9))
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        tr.program.append((e.name, e.start_ns * 1e-9,
+                                           e.end_ns * 1e-9,
+                                           {k: v for k, v in e.stats}))
+    tr.program.sort(key=lambda ev: (ev[1], -ev[2]))
     return tr
 
 

@@ -6,6 +6,9 @@ reference (``bench/references``) regenerates any single layer from the
 same seed with ``layer_leaf``.  Every leaf is drawn from its own key,
 ``fold_in(fold_in(seed_key, leaf index), layer)``, so a layer can be
 rebuilt alone and a stacked leaf equals its layers drawn one by one.
+Which leaves there are, with their shapes and stds, the configuration's
+block family says (``layer_leaves`` and ``top_leaves`` of
+``bench/families/<model_type>.py``).
 
 Matrices are normal with variance 1/fan_in, embedding tables normal with
 std 0.02 (the program's ``init_style="normal"``), norm scales ones.  The
@@ -13,7 +16,7 @@ values are drawn in float32 and rounded once to the served dtype.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -26,36 +29,6 @@ def seed_key(seed: int):
     32 bits, so the high bits are folded in."""
     key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
     return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
-
-
-def layer_leaves(cfg: Dict) -> List[Tuple[str, Tuple[int, ...], float]]:
-    """(path, per-layer shape, std) of every layer leaf; std 0 = ones."""
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    hd = d // cfg["num_attention_heads"]
-    kd = cfg["num_key_value_heads"] * hd
-    ad = cfg["num_attention_heads"] * hd
-    leaves = []
-    if cfg["block_style"] == "standard":
-        leaves.append(("attn/wq", (d, ad), d ** -0.5))
-    elif cfg["block_style"] != "residual_qpfree":
-        raise ValueError(f"unknown block_style {cfg['block_style']!r}")
-    leaves += [("attn/wk", (d, kd), d ** -0.5),
-               ("attn/wv", (d, kd), d ** -0.5)]
-    if cfg["block_style"] == "standard":
-        leaves.append(("attn/wp", (ad, d), ad ** -0.5))
-    leaves += [("ffn/w_gate", (d, ff), d ** -0.5),
-               ("ffn/w_up", (d, ff), d ** -0.5),
-               ("ffn/w_down", (ff, d), ff ** -0.5),
-               ("norm1/scale", (d,), 0.0),
-               ("norm2/scale", (d,), 0.0)]
-    return leaves
-
-
-def top_leaves(cfg: Dict) -> List[Tuple[str, Tuple[int, ...], float]]:
-    v, d = padded_vocab(cfg), cfg["hidden_size"]
-    return [("embed/table", (v, d), EMBED_STD),
-            ("unembed/table", (v, d), EMBED_STD),
-            ("final_norm/scale", (d,), 0.0)]
 
 
 def padded_vocab(cfg: Dict) -> int:
@@ -101,15 +74,15 @@ def _nest(flat: Dict[str, jnp.ndarray]) -> Dict:
     return out
 
 
-def build_params(seed_k, cfg: Dict, dtype):
-    """The serving pytree (traceable): embed/unembed tables, stacked
-    ``layers`` and the final norm, as ``repro.models`` lays them out."""
+def build_params(seed_k, family, cfg: Dict, dtype):
+    """The serving pytree (traceable): the family's top leaves and its
+    stacked ``layers``, as ``repro.models`` lays them out."""
     n = cfg["num_hidden_layers"]
     flat = {}
-    for path, shape, std in top_leaves(cfg):
+    for path, shape, std in family.top_leaves(cfg):
         flat[path] = top_leaf(seed_k, path, shape, std, dtype)
     layers = {}
-    for path, shape, std in layer_leaves(cfg):
+    for path, shape, std in family.layer_leaves(cfg):
         keys = jax.vmap(lambda i, p=path: _leaf_key(seed_k, p, i))(
             jnp.arange(n))
         layers[path] = jax.vmap(
@@ -119,7 +92,7 @@ def build_params(seed_k, cfg: Dict, dtype):
     return params
 
 
-def make_params(cfg: Dict, seed: int, dtype=jnp.bfloat16):
+def make_params(family, cfg: Dict, seed: int, dtype=jnp.bfloat16):
     """Build every weight on the default device in one jitted call."""
-    fn = jax.jit(lambda k: build_params(k, cfg, dtype))
+    fn = jax.jit(lambda k: build_params(k, family, cfg, dtype))
     return fn(seed_key(seed))
