@@ -16,7 +16,8 @@ block family's leaves say):
 
 Rows are packed: several sequences share a row, told apart by segment
 ids, each with its own positions.  The reference runs layer by layer (a
-scan that draws each layer's weights from the seed inside the step), in
+scan that draws each layer's weights from the seed inside the step and
+hands the block the layer's index and window, ``bench.windows``), in
 float32 at ``Precision.HIGHEST``, with attention and the unembedding in
 blocks of query rows, so that it fits beside nothing else on the chip.
 
@@ -27,17 +28,24 @@ bfloat16 that a later change might be tempted to take.
 from __future__ import annotations
 
 import functools
-from typing import Dict
+from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench.windows import layer_windows
 
 HI = jax.lax.Precision.HIGHEST
 QUERY_BLOCK = 512
 FP8_MAX = 448.0
+
+
+class Layer(NamedTuple):
+    """Which layer a block computes, as the layer scan traces it."""
+    index: jax.Array  # int32, 0 first
+    window: jax.Array  # int32, its attention window; 0 = full attention
 
 
 def _fp8_round(w):
@@ -78,8 +86,9 @@ def _rope(x, pos, theta):
 
 
 def _attention(q, k, v, pos, seg, window):
-    """Causal, windowed, segment-masked GQA.  q (R,S,H,D), k/v (R,S,Hk,D)
-    -> (R, S, H*D), in blocks of ``QUERY_BLOCK`` query rows."""
+    """Causal, windowed (``window`` 0: full), segment-masked GQA.
+    q (R,S,H,D), k/v (R,S,Hk,D) -> (R, S, H*D), in blocks of
+    ``QUERY_BLOCK`` query rows."""
     R, S, H, D = q.shape
     Hk = k.shape[2]
     G = H // Hk
@@ -94,9 +103,8 @@ def _attention(q, k, v, pos, seg, window):
         s = jnp.einsum("rqhgd,rkhd->rhgqk", qq, k, precision=HI)
         s = s / np.sqrt(D)
         ok = ((qs[:, :, None] == seg[:, None, :]) & (qs[:, :, None] >= 0)
-              & (pos[:, None, :] <= qp[:, :, None]))
-        if window:
-            ok = ok & (qp[:, :, None] - pos[:, None, :] < window)
+              & (pos[:, None, :] <= qp[:, :, None])
+              & ((window == 0) | (qp[:, :, None] - pos[:, None, :] < window)))
         s = jnp.where(ok[:, None, None], s, -jnp.inf)
         m = jnp.max(s, axis=-1, keepdims=True)
         m = jnp.where(jnp.isfinite(m), m, 0.0)
@@ -109,11 +117,11 @@ def _attention(q, k, v, pos, seg, window):
     return out.transpose(1, 0, 2, 3).reshape(R, S, H * D)
 
 
-def attend(cfg, lw, x, pos, seg):
+def attend(cfg, lw, x, pos, seg, window):
     """One layer's attention over the normed stream ``x`` (R, S, d): Q (or
-    the stream itself), K and V, RoPE, windowed GQA and P."""
+    the stream itself), K and V, RoPE, GQA within ``window`` (the
+    layer's; 0 = full) and P."""
     theta = cfg["rope_theta"]
-    window = cfg.get("sliding_window") or 0
     R, S, d = x.shape
     H, Hk = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     D = d // H
@@ -131,9 +139,10 @@ def attend(cfg, lw, x, pos, seg):
     return a
 
 
-def _block(cfg, lw, h, pos, seg):
+def _block(cfg, lw, h, pos, seg, layer):
     eps = cfg["rms_norm_eps"]
-    h = h + attend(cfg, lw, rms(h, lw["norm1/scale"], eps), pos, seg)
+    h = h + attend(cfg, lw, rms(h, lw["norm1/scale"], eps), pos, seg,
+                   layer.window)
     x = rms(h, lw["norm2/scale"], eps)
     g = jnp.dot(x, lw["ffn/w_gate"], precision=HI)
     u = jnp.dot(x, lw["ffn/w_up"], precision=HI)
@@ -152,11 +161,13 @@ def make_forward(cfg: Dict, family, mode: str = "served"):
 
 def forward(cfg: Dict, family, block, mode: str = "served"):
     """``make_forward`` with the layer ``block(cfg, layer weights by path,
-    h, positions, segs) -> h`` of another family: the embedding, the
-    layers drawn one by one, the final RMSNorm and the blocked
-    unembedding stay as they are."""
+    h, positions, segs, layer) -> h`` of another family (``layer``: a
+    ``Layer``, whose index and window the block may choose by): the
+    embedding, the layers drawn one by one, the final RMSNorm and the
+    blocked unembedding stay as they are."""
     served = jnp.dtype(cfg["torch_dtype"])
     n_layers = cfg["num_hidden_layers"]
+    windows = jnp.asarray(layer_windows(cfg), jnp.int32)
     vocab = cfg["vocab_size"]
     leaves = family.layer_leaves(cfg)
     top = {p: (s, sd) for p, s, sd in family.top_leaves(cfg)}
@@ -168,12 +179,13 @@ def forward(cfg: Dict, family, block, mode: str = "served"):
                  std=top["embed/table"][1])
         h = emb[tokens]
 
-        def layer(hh, i):
-            lw = {p: _weight(seed_k, p, i, s, sd, served, mode)
+        def layer(hh, at):
+            lw = {p: _weight(seed_k, p, at.index, s, sd, served, mode)
                   for p, s, sd in leaves}
-            return block(cfg, lw, hh, pos, seg), None
+            return block(cfg, lw, hh, pos, seg, at), None
 
-        h, _ = jax.lax.scan(layer, h, jnp.arange(n_layers))
+        h, _ = jax.lax.scan(layer, h, Layer(
+            jnp.arange(n_layers), windows))
         h = rms(h, tw("final_norm/scale", shape=top["final_norm/scale"][0],
                       std=0.0), cfg["rms_norm_eps"])
         un = tw("unembed/table", shape=top["unembed/table"][0],

@@ -27,9 +27,10 @@ def _experts(cfg, lw, x):
     return jnp.einsum("rse,rsed->rsd", weight, y, precision=M.HI)
 
 
-def _block(cfg, lw, h, pos, seg):
+def _block(cfg, lw, h, pos, seg, layer):
     eps = cfg["rms_norm_eps"]
-    h = h + M.attend(cfg, lw, M.rms(h, lw["norm1/scale"], eps), pos, seg)
+    h = h + M.attend(cfg, lw, M.rms(h, lw["norm1/scale"], eps), pos, seg,
+                     layer.window)
     return h + _experts(cfg, lw, M.rms(h, lw["norm2/scale"], eps))
 
 

@@ -4,7 +4,8 @@ the harness gave before it had families: the same ``ModelConfig``, the
 same weights leaf by leaf, the same counts (values recorded from the
 harness before the move).  And a family that is not Mistral's, added as
 files only, is served and judged: a sound run is correct, one with the
-program's experts per token one lower is not."""
+program's experts per token one lower is not.  So is a configuration
+whose window binds: correct, and not correct with the window dropped."""
 import dataclasses
 import json
 import shutil
@@ -133,30 +134,31 @@ def test_window_model_flops_sums_the_familys_counts():
     assert flops.window_model_flops(fam, cfg, steps, 0.0, 2.0) == want
 
 
-TOY = Path(__file__).resolve().parent / "data" / "toy_moe"
-TOY_FILES = ("families/toy_moe.py", "references/toy_moe.py",
-             "configs/toy-moe.json", "traffic/tiny.json")
+DATA = Path(__file__).resolve().parent / "data"
 TOY_SEED = 2 ** 33 + 11
 
 
-def toy_benchmark(tmp_path):
-    """A checkout of the benchmark with the toy family's files added (a
-    family, its reference, a configuration and a traffic mix) and entries
-    for them in ``BENCHMARK.json``; no harness file is edited."""
+def toy_benchmark(tmp_path, toy="toy_moe", config="toy-moe",
+                  traffic="tiny"):
+    """A checkout of the benchmark with a toy's files added (for the toy
+    mixture of experts a family, its reference, a configuration and a
+    traffic mix) and entries for its cell ``<config>.<traffic>`` in
+    ``BENCHMARK.json``; no harness file is edited."""
     bench = tmp_path / "bench"
     shutil.copytree(ROOT / "bench", bench,
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    for name in TOY_FILES:
-        dst = bench / name
+    for src in sorted(p for p in (DATA / toy).rglob("*")
+                      if p.suffix in (".py", ".json")):
+        dst = bench / src.relative_to(DATA / toy)
         assert not dst.exists()
         dst.parent.mkdir(exist_ok=True)
-        shutil.copy(TOY / name, dst)
+        shutil.copy(src, dst)
     doc = json.loads((ROOT / "BENCHMARK.json").read_text())
-    doc["configs"].append({"name": "toy-moe", "source": "x",
-                           "file": "bench/configs/toy-moe.json",
+    doc["configs"].append({"name": config, "source": "x",
+                           "file": f"bench/configs/{config}.json",
                            "reduced": [], "why": "x"})
-    doc["workloads"].append({"name": "toy-moe.tiny", "config": "toy-moe",
-                             "traffic": "tiny", "chips": 1, "why": "x"})
+    doc["workloads"].append({"name": f"{config}.{traffic}", "config": config,
+                             "traffic": traffic, "chips": 1, "why": "x"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
     return tmp_path, bench
 
@@ -225,3 +227,49 @@ def test_the_toy_reference_matches_the_programs_forward(tmp_path):
                   jnp.zeros((1, S), jnp.int32), toks))
     assert np.abs(best - lg.max(-1)).max() <= 1e-5 * np.abs(lg).max()
     assert (arg == lg.argmax(-1)).all()
+
+
+class NoWindow(Spec):
+    """The program run without the window the file states, set through
+    the family's ``program_config``."""
+
+    def family(self, cfg):
+        fam = super().family(cfg)
+        build = fam.program_config
+        fam.program_config = lambda c: build(c).with_(sliding_window=0)
+        return fam
+
+
+# a Mistral-family toy with one window of 32 for every layer, served
+# through the paged ring in chunks of one page.  Over seven seeds on the
+# CPU, sound runs read a widest gap of 0.0 and runs with the window
+# dropped 0.71-0.98, the longest compared request 156-170 positions; the
+# toy file's limit is 0.01
+@pytest.mark.parametrize("spec_cls,correct", [(Spec, True),
+                                              (NoWindow, False)],
+                         ids=["sound", "window_dropped"])
+def test_a_window_that_binds_is_served_and_judged(tmp_path, monkeypatch,
+                                                  spec_cls, correct):
+    from bench import check
+    root, bench = toy_benchmark(tmp_path, "toy_window", "toy-window",
+                                "tiny_window")
+    spec = spec_cls(root, bench)
+    cfg = spec.config("toy-window")
+    assert spec.family(cfg).program_config(cfg).sliding_window == \
+        (32 if correct else 0)
+    compared = []
+    sample = check.sample
+
+    def recorded(*args, **kw):
+        placed = sample(*args, **kw)
+        compared.extend(len(f.prompt) + len(f.out) for _, _, f in placed)
+        return placed
+
+    monkeypatch.setattr(check, "sample", recorded)
+    out = run_cell(spec, "toy-window.tiny_window", TOY_SEED, 1.5, False,
+                   impl="xla", allow_cpu=True)
+    assert max(compared) > 2 * 32
+    assert out["correct"] is correct, out["compared"]
+    gap = out["compared"]["worst_logit_gap"]
+    assert (gap["value"] <= gap["limit"]) is correct
+    assert out["attempted"] > 0 and out["failed"] == 0

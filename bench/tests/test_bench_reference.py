@@ -13,6 +13,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import tiny, weights  # noqa: E402
+from bench.references import mistral as M  # noqa: E402
 from bench.references.mistral import make_forward  # noqa: E402
 from bench.spec import Spec  # noqa: E402
 
@@ -51,6 +52,103 @@ def test_reference_matches_program_forward(style, window):
     want = np.take_along_axis(lg, np.asarray(tg)[..., None], -1)[..., 0]
     assert np.abs(got - want).max() <= 1e-5 * scale
     assert (arg == lg.argmax(-1)).all()
+
+
+def two_rows():
+    """Tokens, positions, segments and targets of two rows of 64: one
+    sequence, and two of 32 packed."""
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 64), 0, 128)
+    pos = jnp.stack([jnp.arange(64), jnp.tile(jnp.arange(32), 2)])
+    seg = jnp.stack([jnp.zeros(64, jnp.int32), jnp.repeat(jnp.arange(2), 32)])
+    return toks, pos, seg, jnp.roll(toks, -1, axis=1)
+
+
+def run(cfg, rows):
+    return [np.asarray(x) for x in make_forward(cfg, FAMILY)(
+        weights.seed_key(SEED), *rows)]
+
+
+# (sum, sum of squares) in float64 of the best logit and of the target's
+# logit at every position of ``two_rows``, and the sum of the argmax ids,
+# as the reference computed them with one window read for the whole model
+REFERENCE_SUMS = {
+    ("standard", 0): ((53.15170119702816, 22.610460471038543),
+                      (0.9736517178826034, 3.236639408845443), 7703),
+    ("standard", 8): ((54.37535834312439, 23.697321766000133),
+                      (-0.021363864827435464, 3.497964915799473), 7894),
+    ("residual_qpfree", 0): ((53.41315001249313, 22.870557472508114),
+                             (-1.5810364615172148, 3.160433727818896), 8835),
+    ("residual_qpfree", 8): ((52.966913878917694, 22.53185937648277),
+                             (-2.640603097388521, 3.940877036804339), 9132),
+}
+
+
+@pytest.mark.parametrize("style", ["standard", "residual_qpfree"])
+@pytest.mark.parametrize("window", [0, 8])
+def test_reference_outputs_are_the_ones_it_computed(style, window):
+    best, got, arg = run(tiny_cfg(style, window), two_rows())
+
+    def sums(x):
+        x = x.astype(np.float64)
+        return float(np.sum(x)), float(np.sum(x * x))
+
+    assert (sums(best), sums(got), int(arg.sum())) == \
+        REFERENCE_SUMS[(style, window)]
+
+
+def windowed(layer_types=None, window=8):
+    """Four tiny layers, optionally with a pattern of layer types."""
+    cfg = dict(tiny_cfg("standard", window), num_hidden_layers=4)
+    if layer_types is not None:
+        cfg["layer_types"] = layer_types
+    return cfg
+
+
+def composed(cfg, windows, rows):
+    """The reference's embedding, ``_block`` layer by layer at the given
+    windows, its final norm and the unembedding, without the layer scan:
+    (best logit, argmax id) at every position."""
+    toks, pos, seg, _ = rows
+    key = weights.seed_key(SEED)
+    top = {p: (s, sd) for p, s, sd in FAMILY.top_leaves(cfg)}
+
+    def w(path, layer=None, shape=None, std=None):
+        if shape is None:
+            shape, std = top[path]
+        return M._weight(key, path, layer, shape, std, jnp.float32, "served")
+
+    h = w("embed/table")[toks]
+    for i, window in enumerate(windows):
+        lw = {p: w(p, i, s, sd) for p, s, sd in FAMILY.layer_leaves(cfg)}
+        h = M._block(cfg, lw, h, pos, seg,
+                     M.Layer(jnp.int32(i), jnp.int32(window)))
+    h = M.rms(h, w("final_norm/scale"), cfg["rms_norm_eps"])
+    lg = jnp.einsum("rsd,vd->rsv", h, w("unembed/table"),
+                    precision=M.HI)[..., :cfg["vocab_size"]]
+    return np.asarray(lg.max(-1)), np.asarray(lg.argmax(-1))
+
+
+def test_each_layer_masks_at_its_own_window():
+    """Three sliding layers of window 8 and a full one, as Mellum2's
+    ``layer_types`` repeat them, over rows of 32 and 64 positions."""
+    rows = two_rows()
+    sliding, full = "sliding_attention", "full_attention"
+    uniform = run(windowed(), rows)
+    none = run(windowed(window=0), rows)
+    for got, want in ((run(windowed([sliding] * 4), rows), uniform),
+                      (run(windowed([full] * 4), rows), none)):
+        for a, b in zip(got, want):
+            assert (a == b).all()
+    mixed_cfg = windowed([sliding] * 3 + [full])
+    mixed = run(mixed_cfg, rows)
+    best, arg = composed(mixed_cfg, [8, 8, 8, 0], rows)
+    np.testing.assert_allclose(mixed[0], best, rtol=1e-5, atol=1e-6)
+    assert (mixed[2] == arg).all()
+    pos = np.asarray(rows[1])
+    for other in (uniform, none):
+        diff = np.abs(mixed[0] - other[0])
+        assert diff[pos < 8].max() <= 1e-5  # the window holds them all
+        assert (diff[pos > 8] > 1e-6).all()
 
 
 def test_packed_rows_equal_separate_rows():

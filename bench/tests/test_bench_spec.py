@@ -12,6 +12,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench.flops import COUNTS  # noqa: E402
 from bench.spec import Spec  # noqa: E402
+from bench.windows import layer_windows  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
@@ -71,10 +72,7 @@ def test_committed_benchmark_resolves_and_keeps_its_contract():
         for name in ("program_config", "layer_leaves", "top_leaves") \
                 + COUNTS:
             assert callable(getattr(family, name)), name
-        mix = spec.traffic(wl["traffic"])
-        # the window never binds: pages stay absolutely addressed
-        if cfg.get("sliding_window"):
-            assert mix["engine"]["max_len"] <= cfg["sliding_window"]
+        spec.traffic(wl["traffic"])
         reported = [m["name"] for m in spec.end_to_end(wl["name"])]
         assert "setup_s" in reported and len(reported) >= 2
         layer = spec.per_layer(wl["name"])
@@ -87,6 +85,9 @@ def test_committed_benchmark_resolves_and_keeps_its_contract():
         assert sorted(body["reduced"]) == sorted(c["reduced"])
         assert body["source"] == c["source"]
         assert any(wl["config"] == c["name"] for wl in cells)
+        cfg = spec.config(c["name"])
+        if any(layer_windows(cfg)):
+            windows_cap_the_counts(spec.family(cfg), cfg)
     layers = {}
     for m in doc["per_layer"]:
         assert NAME.match(m["name"])
@@ -95,6 +96,32 @@ def test_committed_benchmark_resolves_and_keeps_its_contract():
             assert m["unit"] == "%"
         layers.setdefault(m["layer"], m["layer"])
     assert len(json.dumps(doc)) < 64 * 1024
+
+
+def one_more_position(family, cfg, context):
+    """What one more position of context adds to a token's operations and
+    to one decode step's attention operations and bytes."""
+    def counts(c):
+        return (family.token_flops(cfg, c, logits=False),
+                *family.decode_attention_step(cfg, [c]))
+    return [b - a for a, b in zip(counts(context), counts(context + 1))]
+
+
+def windows_cap_the_counts(family, cfg):
+    """Past its largest window, one more position of context adds the
+    full-attention layers' share of what it adds below its smallest
+    window; a prompt still counts as its positions one by one."""
+    windows = layer_windows(cfg)
+    n_full = windows.count(0)
+    least, most = min(w for w in windows if w), max(windows)
+    below = one_more_position(family, cfg, least // 2)
+    assert all(b > 0 for b in below)
+    for context in (most, most + 1, 2 * most + 3):
+        past = one_more_position(family, cfg, context)
+        assert [p * len(windows) for p in past] == [b * n_full for b in below]
+    lo, hi = max(0, most - 5), most + 20
+    assert family.prefill_flops(cfg, lo, hi, logits=False) == sum(
+        family.token_flops(cfg, p + 1, logits=False) for p in range(lo, hi))
 
 
 def test_reference_computes_what_the_program_runs_where_it_departs():
