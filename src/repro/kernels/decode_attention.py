@@ -41,7 +41,12 @@ stored: with absolute addressing logical block j covers positions
 sliding-window tables bounded at ceil(window/bs)+1 recycled slots, see
 ``kernels.paging``) slot j holds the latest absolute block ≡ j (mod
 ring) not beyond the query's block, so the kernel reconstructs positions
-from the table index and the query position.  With ``k_scale``/
+from the table index and the query position.  The pool operand is the
+serving cache's whole layer stack (L, NB, bs, Hkv, D) and the layer to
+read is one more scalar-prefetch operand: each DMA reads page ``pid`` of
+layer ``layer`` in place, so the caller's layer scan never slices a
+layer's pool out of the stack (a single-layer pool is passed as
+``pool[None]`` with layer 0).  With ``k_scale``/
 ``v_scale`` the pool holds int8 pages with one float32 scale per (page,
 kv head) (``kernels.quant``): the scales ride along as extra
 scalar-prefetch operands and are applied in VMEM to each page's score
@@ -206,13 +211,15 @@ def pages_per_block(block_size: int, table_blocks: int) -> int:
 def _paged_kernel(*refs, scale: float, window: int, bs: int, mb: int,
                   ppb: int, ring: int, quantized: bool):
     if quantized:
-        (bt_ref, qpos_ref, ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref,
-         kbuf, vbuf, sem, first_buf, q_scr, m_scr, l_scr, acc_scr) = refs
+        (bt_ref, qpos_ref, layer_ref, ks_ref, vs_ref, q_ref, k_hbm, v_hbm,
+         o_ref, kbuf, vbuf, sem, first_buf, q_scr, m_scr, l_scr,
+         acc_scr) = refs
     else:
-        (bt_ref, qpos_ref, q_ref, k_hbm, v_hbm, o_ref,
+        (bt_ref, qpos_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
          kbuf, vbuf, sem, first_buf, q_scr, m_scr, l_scr, acc_scr) = refs
     b = pl.program_id(0)
     qpos = qpos_ref[b]
+    layer = layer_ref[0]
     _, n_kv, g, _ = q_ref.shape
     hq = n_kv * g
     rows = bs * n_kv  # a page's rows in the (P·bs·Hkv, D) view of a block
@@ -242,10 +249,10 @@ def _paged_kernel(*refs, scale: float, window: int, bs: int, mb: int,
     def copies(pages, slot):
         for p, (pid, first) in enumerate(pages):
             yield (first < DEAD, p,
-                   pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[slot, p],
-                                         sem.at[0, slot]),
-                   pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[slot, p],
-                                         sem.at[1, slot]))
+                   pltpu.make_async_copy(k_hbm.at[layer, pid],
+                                         kbuf.at[slot, p], sem.at[0, slot]),
+                   pltpu.make_async_copy(v_hbm.at[layer, pid],
+                                         vbuf.at[slot, p], sem.at[1, slot]))
 
     def fetch(pages, slot):
         for live, p, ck, cv in copies(pages, slot):
@@ -376,13 +383,14 @@ def _paged_kernel(*refs, scale: float, window: int, bs: int, mb: int,
 
 def decode_attention_paged_bsd(
     q: jnp.ndarray,  # (B, Hkv, G, D) — grouped query heads (or stream view)
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — physical page pool (int8 if q8)
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D)
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) — layer stack (int8 if q8)
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D)
     block_tables: jnp.ndarray,  # (B, MB) int32 physical page ids; -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack to read
     *,
-    k_scale=None,  # (NB, Hkv) float32 per-(page, head) scales — int8 pools
-    v_scale=None,  # (NB, Hkv) float32
+    k_scale=None,  # (L, NB, Hkv) f32 per-(page, head) scales — int8 pools
+    v_scale=None,  # (L, NB, Hkv) float32
     sliding_window: int = 0,
     ring_blocks: int = 0,
     interpret: bool = False,
@@ -392,14 +400,15 @@ def decode_attention_paged_bsd(
     live extent, gathering each block's live pages by DMA into VMEM while
     the previous block computes; a block with no live page costs neither
     a DMA nor a dot.  The pools stay in HBM in the serving cache's native
-    (…, bs, Hkv, D) page layout.  ``ring_blocks`` > 0 means the table is
+    (L, NB, bs, Hkv, D) layout and every DMA reads layer ``layer`` of
+    the stack in place.  ``ring_blocks`` > 0 means the table is
     ring-addressed (windowed requests recycle pages; see
     ``kernels.paging``) and slot positions are reconstructed from the
     query position.  ``k_scale``/``v_scale`` switch to an int8 pool: its
     pages are cast in VMEM and each page's per-head scales applied to its
     score and probability columns."""
     B, Hkv, G, D = q.shape
-    NB, bs = k_pool.shape[0], k_pool.shape[1]
+    bs = k_pool.shape[2]
     MB = block_tables.shape[1]
     quantized = k_scale is not None
     ppb = pages_per_block(bs, MB)
@@ -411,10 +420,14 @@ def decode_attention_paged_bsd(
     def head(b, *_):
         return (b, 0, 0, 0)
 
-    prefetch = [block_tables.astype(jnp.int32), q_position.astype(jnp.int32)]
-    if quantized:  # flat (NB·Hkv,) scale vectors: page-major, like the pool
-        prefetch += [k_scale.astype(jnp.float32).reshape(-1),
-                     v_scale.astype(jnp.float32).reshape(-1)]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    prefetch = [block_tables.astype(jnp.int32), q_position.astype(jnp.int32),
+                layer]
+    if quantized:  # the layer's flat (NB·Hkv,) scale vectors, page-major
+        # like the pool: (NB, Hkv) is small, the whole (L·NB·Hkv) is not
+        prefetch += [
+            jax.lax.dynamic_index_in_dim(s, layer[0], keepdims=False)
+            .astype(jnp.float32).reshape(-1) for s in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B,),

@@ -190,24 +190,26 @@ def ssd_scan(
 @partial(jax.jit, static_argnames=("sliding_window", "interpret"))
 def decode_attention_paged(
     q: jnp.ndarray,  # (B, Hq, D)
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — physical page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D)
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) — layer-stacked page pool
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D)
     *,
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     sliding_window: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Generic decode attention over a paged KV pool (block-table gather).
+    """Generic decode attention over layer ``layer`` of a paged KV pool
+    (block-table gather, read in place from the stack).
 
     Ring addressing (windowed tables bounded at ceil(window/bs)+1 recycled
     slots) is derived from the static window and the table width — see
     ``kernels.paging`` — so callers never thread a ring flag."""
-    ring = paged_ring_active(sliding_window, k_pool.shape[1],
+    ring = paged_ring_active(sliding_window, k_pool.shape[2],
                              block_tables.shape[1])
     out = decode_attention_paged_bsd(
-        _grouped(q, k_pool.shape[2]), k_pool, v_pool, block_tables,
-        q_position, sliding_window=sliding_window, ring_blocks=ring,
+        _grouped(q, k_pool.shape[3]), k_pool, v_pool, block_tables,
+        q_position, layer, sliding_window=sliding_window, ring_blocks=ring,
         interpret=interpret)
     return out.reshape(q.shape)
 
@@ -216,26 +218,28 @@ def decode_attention_paged(
                                    "interpret"))
 def decode_attention_paged_merged(
     u: jnp.ndarray,  # (B, d_model) — RoPE'd residual stream = merged query
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — K* page pool, native layout
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — V* page pool
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) — K* page pool, native layout
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) — V* page pool
     *,
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     n_kv_heads: int,
     sliding_window: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Merged (Q/P-removed) decode fast path over a paged KV pool.  Ring
-    addressing derived as in ``decode_attention_paged``."""
+    """Merged (Q/P-removed) decode fast path over layer ``layer`` of a
+    paged KV pool.  Ring addressing derived as in
+    ``decode_attention_paged``."""
     B, d = u.shape
-    Hkv, D = k_pool.shape[2], k_pool.shape[3]
+    Hkv, D = k_pool.shape[3], k_pool.shape[4]
     assert Hkv == n_kv_heads, (Hkv, n_kv_heads)
     assert d % D == 0 and (d // D) % Hkv == 0, (d, D, Hkv)
-    ring = paged_ring_active(sliding_window, k_pool.shape[1],
+    ring = paged_ring_active(sliding_window, k_pool.shape[2],
                              block_tables.shape[1])
     out = decode_attention_paged_bsd(
         _grouped(u.reshape(B, d // D, D), Hkv), k_pool, v_pool, block_tables,
-        q_position, sliding_window=sliding_window, ring_blocks=ring,
+        q_position, layer, sliding_window=sliding_window, ring_blocks=ring,
         interpret=interpret)
     return out.reshape(B, d)
 
@@ -247,25 +251,26 @@ def decode_attention_paged_merged(
 @partial(jax.jit, static_argnames=("sliding_window", "interpret"))
 def decode_attention_paged_q8(
     q: jnp.ndarray,  # (B, Hq, D)
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8 page pool
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8
     *,
-    k_scale: jnp.ndarray,  # (NB, Hkv) float32 per-(page, head) scales
-    v_scale: jnp.ndarray,  # (NB, Hkv) float32
+    k_scale: jnp.ndarray,  # (L, NB, Hkv) float32 per-(page, head) scales
+    v_scale: jnp.ndarray,  # (L, NB, Hkv) float32
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     sliding_window: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Generic decode attention over an int8 paged pool — the q8 face of
-    ``decode_attention_paged``: same block-table gather and ring
-    derivation, with the gathered page dequantized inside the kernel from
-    its scalar-prefetched scale."""
-    ring = paged_ring_active(sliding_window, k_pool.shape[1],
+    ``decode_attention_paged``: same block-table gather, layer addressing
+    and ring derivation, with the gathered page dequantized inside the
+    kernel from its scalar-prefetched scale."""
+    ring = paged_ring_active(sliding_window, k_pool.shape[2],
                              block_tables.shape[1])
     out = decode_attention_paged_bsd(
-        _grouped(q, k_pool.shape[2]), k_pool, v_pool, block_tables,
-        q_position, k_scale=k_scale, v_scale=v_scale,
+        _grouped(q, k_pool.shape[3]), k_pool, v_pool, block_tables,
+        q_position, layer, k_scale=k_scale, v_scale=v_scale,
         sliding_window=sliding_window, ring_blocks=ring, interpret=interpret)
     return out.reshape(q.shape)
 
@@ -274,27 +279,28 @@ def decode_attention_paged_q8(
                                    "interpret"))
 def decode_attention_paged_q8_merged(
     u: jnp.ndarray,  # (B, d_model) — RoPE'd residual stream = merged query
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 K* page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 V* page pool
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8 K* page pool
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8 V* page pool
     *,
-    k_scale: jnp.ndarray,  # (NB, Hkv) float32 per-(page, head) scales
-    v_scale: jnp.ndarray,  # (NB, Hkv) float32
+    k_scale: jnp.ndarray,  # (L, NB, Hkv) float32 per-(page, head) scales
+    v_scale: jnp.ndarray,  # (L, NB, Hkv) float32
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     n_kv_heads: int,
     sliding_window: int = 0,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Merged (Q/P-removed) decode fast path over an int8 paged pool."""
     B, d = u.shape
-    Hkv, D = k_pool.shape[2], k_pool.shape[3]
+    Hkv, D = k_pool.shape[3], k_pool.shape[4]
     assert Hkv == n_kv_heads, (Hkv, n_kv_heads)
     assert d % D == 0 and (d // D) % Hkv == 0, (d, D, Hkv)
-    ring = paged_ring_active(sliding_window, k_pool.shape[1],
+    ring = paged_ring_active(sliding_window, k_pool.shape[2],
                              block_tables.shape[1])
     out = decode_attention_paged_bsd(
         _grouped(u.reshape(B, d // D, D), Hkv), k_pool, v_pool, block_tables,
-        q_position, k_scale=k_scale, v_scale=v_scale,
+        q_position, layer, k_scale=k_scale, v_scale=v_scale,
         sliding_window=sliding_window, ring_blocks=ring, interpret=interpret)
     return out.reshape(B, d)
 

@@ -77,27 +77,28 @@ def q8_dequant_seq(ints: jnp.ndarray, scale: jnp.ndarray, out_dtype):
 
 def q8_append_token(pool: jnp.ndarray, scale: jnp.ndarray,
                     new_tok: jnp.ndarray, safe: jnp.ndarray,
-                    off: jnp.ndarray):
-    """Quantize-on-write of one decode token per batch slot.
+                    off: jnp.ndarray, layer):
+    """Quantize-on-write of one decode token per batch slot into layer
+    ``layer`` of the stacked pool, in place.
 
-    pool (NB, bs, Hkv, D) int8, scale (NB, Hkv) f32, new_tok (B, Hkv, D)
-    float, safe (B,) physical page (== NB drops the write — unmapped
-    slot), off (B,) in-page offset.  Monotone scale merge: at off == 0
-    the page is being (re-)entered — fresh alloc, ring recycle, or
-    detach — so its stale scale is ignored and reset from this token;
+    pool (L, NB, bs, Hkv, D) int8, scale (L, NB, Hkv) f32, new_tok (B,
+    Hkv, D) float, safe (B,) physical page (== NB drops the write —
+    unmapped slot), off (B,) in-page offset.  Monotone scale merge: at
+    off == 0 the page is being (re-)entered — fresh alloc, ring recycle,
+    or detach — so its stale scale is ignored and reset from this token;
     at off > 0 the page's live prefix was written by prefill/chunk/
     earlier decode steps under a valid scale, which only GROWS
     (new = max(old, tok)), with the stored ints rescaled by old/new <= 1
     when it does (a no-op round when it does not)."""
-    NB = pool.shape[0]
+    NB = pool.shape[1]
     read = jnp.minimum(safe, NB - 1)  # in-range gather; dropped writes
     newf = new_tok.astype(jnp.float32)  # (B, Hkv, D)
     tok_scale = jnp.maximum(jnp.max(jnp.abs(newf), axis=-1) / Q8_MAX, Q8_EPS)
-    old = scale[read]  # (B, Hkv)
+    old = scale[layer, read]  # (B, Hkv)
     fresh = (off == 0)[:, None]  # (B, 1) — first write of this page
     base = jnp.where(fresh, Q8_EPS, old)
     new_scale = jnp.maximum(base, tok_scale)
-    page = pool[read].astype(jnp.float32)  # (B, bs, Hkv, D)
+    page = pool[layer, read].astype(jnp.float32)  # (B, bs, Hkv, D)
     ratio = jnp.where(fresh, 1.0, base / new_scale)  # <= 1; fresh skips
     page = jnp.clip(jnp.round(page * ratio[:, None, :, None]),
                     -Q8_MAX, Q8_MAX)
@@ -105,6 +106,6 @@ def q8_append_token(pool: jnp.ndarray, scale: jnp.ndarray,
     page = jax.vmap(
         lambda pg, t, o: jax.lax.dynamic_update_slice(pg, t[None], (o, 0, 0))
     )(page, tok_q, off)
-    pool = pool.at[safe].set(page.astype(jnp.int8), mode="drop")
-    scale = scale.at[safe].set(new_scale, mode="drop")
+    pool = pool.at[layer, safe].set(page.astype(jnp.int8), mode="drop")
+    scale = scale.at[layer, safe].set(new_scale, mode="drop")
     return pool, scale

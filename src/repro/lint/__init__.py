@@ -10,8 +10,9 @@ before a single token is decoded.  This package checks them:
   walker      shared jaxpr IR traversal (scan/cond/pjit/pallas bodies)
   rules       Finding / LintTarget / LintRule + the rule registry
   builtin     the built-in rules (NoForbiddenMatmul, NoOversizedBuffer,
-              DonationEffective, NoDtypePromotionDrift,
-              NoHostTransferInStepLoop, NoDequantizedPoolBuffer)
+              DonationEffective, NoPerLayerPoolSlice,
+              NoDtypePromotionDrift, NoHostTransferInStepLoop,
+              NoDequantizedPoolBuffer)
   sweep       sweep() — lint EVERY registered (cache_kind, style, impl)
               decode/prefill/chunk backend combo, zero per-combo code
   aliasing    audit_engine() — the host-aliasing race detector
@@ -26,7 +27,8 @@ from repro.lint.builtin import (BUILTIN_RULES, DonationEffective,  # noqa: F401
                                 NoDequantizedPoolBuffer,
                                 NoDtypePromotionDrift, NoForbiddenMatmul,
                                 NoHostTransferInObsHooks,
-                                NoHostTransferInStepLoop, NoOversizedBuffer)
+                                NoHostTransferInStepLoop, NoOversizedBuffer,
+                                NoPerLayerPoolSlice)
 from repro.lint.rules import (Finding, LintRule, LintTarget,  # noqa: F401
                               all_rules, get_rule, register_rule,
                               registered_rules, run_rules)

@@ -15,6 +15,13 @@ classes this repo has actually shipped, each as a checkable invariant.
                           downgrades donation to a full pool copy per
                           step, the kind of perf regression nothing
                           functional ever catches
+  NoPerLayerPoolSlice     paged decode and chunk programs hold no value
+                          of one layer's pool shape (NB, bs, Hkv, D):
+                          they carry the (L, …) stack through the layer
+                          scan and address it by layer index, where a
+                          per-layer xs/ys scan slices each layer's pool
+                          out and stacks it back — XLA copies both, and
+                          the whole stack, every step
   NoDtypePromotionDrift   no cache-sized buffer appears at a float dtype
                           wider than the cache dtype — an accidental
                           fp32 shadow of a bf16 pool doubles the HBM the
@@ -134,6 +141,42 @@ class DonationEffective(LintRule):
                    f"donation silently became a copy",
                 detail={"dead_flat_positions": dead,
                         "declared": list(t.donated_flat)})]
+        return []
+
+
+class NoPerLayerPoolSlice(LintRule):
+    """Paged decode and chunk programs address the pool stack in place.
+
+    The pools are (L, NB, bs, Hkv, D) stacks shared by every layer.  A
+    layer scan that takes each layer's pool as its xs and stacks the
+    updated pools as its ys makes XLA copy every layer's pool out and
+    back, and the whole stack once more, on every step — about half the
+    decode step's device time at Mistral-7B widths, none of it attention
+    work.  The paged programs carry the stack through the scan and hand
+    the write and the kernel the layer index instead, so no value of one
+    layer's pool shape (at any dtype) appears anywhere in the program.
+    Gathered table rows are (…, MB, bs, Hkv, D) and pass."""
+
+    name = "NoPerLayerPoolSlice"
+    description = ("no value of one layer's pool shape in a paged decode "
+                   "or chunk program")
+
+    def applies(self, t: LintTarget) -> bool:
+        return t.phase in ("decode", "chunk") \
+            and t.cache_kind in ("paged", "paged_q8") \
+            and bool(t.cache_shapes)
+
+    def check(self, t: LintTarget) -> List[Finding]:
+        layer = {tuple(s[1:]) for s in t.cache_shapes if len(s) == 5}
+        hits = [a for a in walker.iter_avals(t.jaxpr)
+                if hasattr(a, "shape") and tuple(a.shape) in layer]
+        if hits:
+            seen = sorted({(tuple(a.shape), str(a.dtype)) for a in hits})
+            return [self.finding(
+                t, f"{len(hits)} values of one layer's pool shape, e.g. "
+                   f"{seen[:3]} — the layer scan slices the pool stack "
+                   f"instead of addressing it by layer index",
+                detail={"hits": [[list(s), d] for s, d in seen[:10]]})]
         return []
 
 
@@ -293,7 +336,8 @@ class NoDequantizedPoolBuffer(LintRule):
 
 
 BUILTIN_RULES = (NoForbiddenMatmul(), NoOversizedBuffer(),
-                 DonationEffective(), NoDtypePromotionDrift(),
+                 DonationEffective(), NoPerLayerPoolSlice(),
+                 NoDtypePromotionDrift(),
                  NoHostTransferInStepLoop(), NoHostTransferInObsHooks(),
                  NoDequantizedPoolBuffer())
 

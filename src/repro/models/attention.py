@@ -312,28 +312,33 @@ def paged_kv_positions(block_tables: jnp.ndarray, block_size: int,
     return jnp.broadcast_to(jnp.where(mapped, pos, -1), (B, MB * block_size))
 
 
-def _paged_gather(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
-    """(NB, bs, Hkv, D) pool + (B, MB) tables -> (B, MB*bs, Hkv, D) view."""
+def _paged_gather(pool: jnp.ndarray, block_tables: jnp.ndarray,
+                  layer) -> jnp.ndarray:
+    """(L, NB, bs, Hkv, D) pool + (B, MB) tables -> layer ``layer``'s
+    (B, MB*bs, Hkv, D) view, in one gather from the stack."""
     B, MB = block_tables.shape
-    g = pool[jnp.maximum(block_tables, 0)]  # (B, MB, bs, Hkv, D)
-    return g.reshape(B, MB * pool.shape[1], *pool.shape[2:])
+    g = pool[layer, jnp.maximum(block_tables, 0)]  # (B, MB, bs, Hkv, D)
+    return g.reshape(B, MB * pool.shape[2], *pool.shape[3:])
 
 
 def decode_attention_core_paged(
     q: jnp.ndarray,  # (B, Hq, D)
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — physical page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D)
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) — layer-stacked page pool
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D)
     *,
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     sliding_window: int = 0,
     impl: str = "xla",
 ) -> jnp.ndarray:
-    """One-token attention against a paged KV pool -> (B, Hq, D).
+    """One-token attention against layer ``layer`` of a paged KV pool ->
+    (B, Hq, D).
 
-    The pallas path hands the pool and table straight to the paged kernel
-    (pages are gathered block-by-block inside the grid); the XLA path
-    densifies the slot's logical view first and defers to the dense core.
+    The pallas path hands the whole stack, the layer and the table
+    straight to the paged kernel (pages are gathered block-by-block inside
+    the grid); the XLA path densifies the slot's logical view of the layer
+    first and defers to the dense core.
     Ring addressing (windowed tables bounded at ceil(window/bs)+1 recycled
     slots) is derived from the window and the table width
     (``kernels.paging``), with positions reconstructed per query.
@@ -343,25 +348,27 @@ def decode_attention_core_paged(
 
         return kops.decode_kernel("paged", "generic")(
             q, k_pool, v_pool, block_tables=block_tables,
-            q_position=q_position, sliding_window=sliding_window,
+            q_position=q_position, layer=layer,
+            sliding_window=sliding_window,
             interpret=(impl == "pallas_interpret"))
 
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     ring = paged_ring_active(sliding_window, bs, block_tables.shape[1])
     return decode_attention_core_positions(
-        q, _paged_gather(k_pool, block_tables),
-        _paged_gather(v_pool, block_tables),
+        q, _paged_gather(k_pool, block_tables, layer),
+        _paged_gather(v_pool, block_tables, layer),
         kv_positions=paged_kv_positions(block_tables, bs, q_position, ring),
         q_position=q_position, sliding_window=sliding_window, impl=impl)
 
 
 def decode_attention_core_paged_merged(
     u: jnp.ndarray,  # (B, d_model) — RoPE'd residual stream (merged query)
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — K* page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) — V* page pool
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) — K* page pool
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) — V* page pool
     *,
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     n_kv_heads: int,
     sliding_window: int = 0,
     impl: str = "xla",
@@ -373,20 +380,21 @@ def decode_attention_core_paged_merged(
     behind a block table instead of a dense per-slot buffer.
     """
     B, d = u.shape
-    D = k_pool.shape[3]
+    D = k_pool.shape[4]
 
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels import ops as kops
 
         return kops.decode_kernel("paged", "merged")(
             u, k_pool, v_pool, block_tables=block_tables,
-            q_position=q_position, n_kv_heads=n_kv_heads,
+            q_position=q_position, layer=layer, n_kv_heads=n_kv_heads,
             sliding_window=sliding_window,
             interpret=(impl == "pallas_interpret"))
 
     out = decode_attention_core_paged(
         u.reshape(B, d // D, D), k_pool, v_pool, block_tables=block_tables,
-        q_position=q_position, sliding_window=sliding_window, impl=impl)
+        q_position=q_position, layer=layer, sliding_window=sliding_window,
+        impl=impl)
     return out.reshape(B, d)
 
 
@@ -395,27 +403,30 @@ def decode_attention_core_paged_merged(
 # ---------------------------------------------------------------------------
 
 def _paged_gather_q8(pool: jnp.ndarray, scale: jnp.ndarray,
-                     block_tables: jnp.ndarray, out_dtype) -> jnp.ndarray:
-    """Densify + dequantize a slot's logical view of an int8 pool:
-    (NB, bs, Hkv, D) int8 + (NB, Hkv) f32 scales + (B, MB) tables ->
-    (B, MB*bs, Hkv, D) ``out_dtype``.  Unmapped blocks gather page 0
-    (masked to -1 positions by callers, as in ``_paged_gather``)."""
+                     block_tables: jnp.ndarray, layer,
+                     out_dtype) -> jnp.ndarray:
+    """Densify + dequantize a slot's logical view of layer ``layer`` of
+    an int8 pool: (L, NB, bs, Hkv, D) int8 + (L, NB, Hkv) f32 scales +
+    (B, MB) tables -> (B, MB*bs, Hkv, D) ``out_dtype``.  Unmapped blocks
+    gather page 0 (masked to -1 positions by callers, as in
+    ``_paged_gather``)."""
     B, MB = block_tables.shape
     bt = jnp.maximum(block_tables, 0)
-    g = pool[bt].astype(jnp.float32)  # (B, MB, bs, Hkv, D)
-    g = g * scale[bt][:, :, None, :, None]
-    return g.reshape(B, MB * pool.shape[1], *pool.shape[2:]).astype(out_dtype)
+    g = pool[layer, bt].astype(jnp.float32)  # (B, MB, bs, Hkv, D)
+    g = g * scale[layer, bt][:, :, None, :, None]
+    return g.reshape(B, MB * pool.shape[2], *pool.shape[3:]).astype(out_dtype)
 
 
 def decode_attention_core_paged_q8(
     q: jnp.ndarray,  # (B, Hq, D)
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8
-    k_scale: jnp.ndarray,  # (NB, Hkv) float32 per-(page, head) scales
-    v_scale: jnp.ndarray,  # (NB, Hkv) float32
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8 page pool
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8
+    k_scale: jnp.ndarray,  # (L, NB, Hkv) float32 per-(page, head) scales
+    v_scale: jnp.ndarray,  # (L, NB, Hkv) float32
     *,
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     sliding_window: int = 0,
     impl: str = "xla",
 ) -> jnp.ndarray:
@@ -431,28 +442,29 @@ def decode_attention_core_paged_q8(
 
         return kops.decode_kernel("paged_q8", "generic")(
             q, k_pool, v_pool, k_scale=k_scale, v_scale=v_scale,
-            block_tables=block_tables, q_position=q_position,
+            block_tables=block_tables, q_position=q_position, layer=layer,
             sliding_window=sliding_window,
             interpret=(impl == "pallas_interpret"))
 
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     ring = paged_ring_active(sliding_window, bs, block_tables.shape[1])
     return decode_attention_core_positions(
-        q, _paged_gather_q8(k_pool, k_scale, block_tables, q.dtype),
-        _paged_gather_q8(v_pool, v_scale, block_tables, q.dtype),
+        q, _paged_gather_q8(k_pool, k_scale, block_tables, layer, q.dtype),
+        _paged_gather_q8(v_pool, v_scale, block_tables, layer, q.dtype),
         kv_positions=paged_kv_positions(block_tables, bs, q_position, ring),
         q_position=q_position, sliding_window=sliding_window, impl=impl)
 
 
 def decode_attention_core_paged_q8_merged(
     u: jnp.ndarray,  # (B, d_model) — RoPE'd residual stream (merged query)
-    k_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 K* page pool
-    v_pool: jnp.ndarray,  # (NB, bs, Hkv, D) int8 V* page pool
-    k_scale: jnp.ndarray,  # (NB, Hkv) float32 per-(page, head) scales
-    v_scale: jnp.ndarray,  # (NB, Hkv) float32
+    k_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8 K* page pool
+    v_pool: jnp.ndarray,  # (L, NB, bs, Hkv, D) int8 V* page pool
+    k_scale: jnp.ndarray,  # (L, NB, Hkv) float32 per-(page, head) scales
+    v_scale: jnp.ndarray,  # (L, NB, Hkv) float32
     *,
     block_tables: jnp.ndarray,  # (B, MB) int32 page ids, -1 unmapped
     q_position: jnp.ndarray,  # (B,) int32
+    layer,  # int32 scalar: the layer of the stack attended
     n_kv_heads: int,
     sliding_window: int = 0,
     impl: str = "xla",
@@ -461,19 +473,19 @@ def decode_attention_core_paged_q8_merged(
     the paper's serving fast path at a quarter of the page-pool HBM
     traffic.  Contract as ``decode_attention_core_paged_merged``."""
     B, d = u.shape
-    D = k_pool.shape[3]
+    D = k_pool.shape[4]
 
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels import ops as kops
 
         return kops.decode_kernel("paged_q8", "merged")(
             u, k_pool, v_pool, k_scale=k_scale, v_scale=v_scale,
-            block_tables=block_tables, q_position=q_position,
+            block_tables=block_tables, q_position=q_position, layer=layer,
             n_kv_heads=n_kv_heads, sliding_window=sliding_window,
             interpret=(impl == "pallas_interpret"))
 
     out = decode_attention_core_paged_q8(
         u.reshape(B, d // D, D), k_pool, v_pool, k_scale, v_scale,
-        block_tables=block_tables, q_position=q_position,
+        block_tables=block_tables, q_position=q_position, layer=layer,
         sliding_window=sliding_window, impl=impl)
     return out.reshape(B, d)

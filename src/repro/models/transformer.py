@@ -1293,19 +1293,13 @@ def forward_step(params, cfg: ModelConfig, token, cache, *,
         ctx = {"length": cache.length, "block_tables": cache.block_tables,
                "impl": impl, "qkv_sharding": qkv_sharding,
                "backend": backend}
-
-        def f(h, xs):
-            lp, lc = xs
-            out, nc = apply_block_step(lp, cfg, "attn", h, lc, ctx)
-            return out, nc
-
-        # q8 stores scan as (pool, scale) pairs — apply_block_step passes
-        # them through to the backend step opaquely
+        # q8 stores travel as (pool, scale) pairs — apply_block_step
+        # passes them through to the backend step opaquely
         stores = {"k": (cache.k, cache.k_scale),
                   "v": (cache.v, cache.v_scale)} if paged_q8 else \
             {"k": cache.k, "v": cache.v}
-        h, ncs = jax.lax.scan(f, h, (params["layers"], stores),
-                              unroll=True if unroll else 1)
+        h, ncs = _scan_pool_layers(params, cfg, h, stores, ctx,
+                                   unroll=unroll)
         if "final_norm" in params:
             h = apply_rmsnorm(params["final_norm"], h)
         table = params["embed"] if cfg.tie_embeddings else params["unembed"]
@@ -1500,47 +1494,78 @@ def init_paged_q8_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
         length=jnp.zeros(*spec["length"]))
 
 
+def _scan_pool_layers(params, cfg: ModelConfig, h, stores, ctx, *,
+                      unroll: bool = False):
+    """Run the attention-only block stack over a paged pool, carrying the
+    whole (L, NB, bs, Hkv, Dh) stores through the scan: each layer's
+    backend step reads and writes its own layer of the stack, by the
+    traced index ``ctx["layer"]``, in place.  No layer's pool is sliced
+    out of the stack or stacked back (XLA copies both, and the whole
+    stack once more when it is both scanned over and rebuilt).  Returns
+    (h, new stores)."""
+    n = layer_plan(cfg)["n"]
+
+    def f(carry, xs):
+        hh, st = carry
+        lp, layer = xs
+        out, nc = apply_block_step(lp, cfg, "attn", hh, st,
+                                   dict(ctx, layer=layer))
+        return (out, nc), None
+
+    (h, stores), _ = jax.lax.scan(
+        f, (h, stores), (params["layers"], jnp.arange(n, dtype=jnp.int32)),
+        unroll=True if unroll else 1)
+    return h, stores
+
+
 def _rope_and_insert_paged(cfg: ModelConfig, q, k_new, v_new, k_pool, v_pool,
-                           block_tables, length):
+                           block_tables, length, layer):
     """RoPE the step's q/k at position ``length`` and scatter the new k/v
-    into each slot's mapped page (page = table[length // bs], offset =
-    length % bs; ring-addressed windowed tables wrap the table index —
-    ``kernels.paging``).  Unmapped slots (idle batch rows) drop the
-    write."""
+    into layer ``layer`` of the stacked pools, at each slot's mapped page
+    (page = table[length // bs], offset = length % bs; ring-addressed
+    windowed tables wrap the table index — ``kernels.paging``).  Unmapped
+    slots (idle batch rows) drop the write."""
     pos = length[:, None]  # (B,1)
     q = apply_rope(q, pos, style=cfg.rope_style, theta=cfg.rope_theta,
                    fraction=cfg.rope_fraction)
     k_new = apply_rope(k_new, pos, style=cfg.rope_style, theta=cfg.rope_theta,
                        fraction=cfg.rope_fraction)
-    NB, bs = k_pool.shape[0], k_pool.shape[1]
+    safe, off = _paged_write_slot(cfg, k_pool, block_tables, length)
+    k_pool = k_pool.at[layer, safe, off].set(
+        k_new[:, 0].astype(k_pool.dtype), mode="drop")
+    v_pool = v_pool.at[layer, safe, off].set(
+        v_new[:, 0].astype(v_pool.dtype), mode="drop")
+    return q, k_pool, v_pool
+
+
+def _paged_write_slot(cfg: ModelConfig, pool, block_tables, length):
+    """(page, in-page offset) each slot's token at ``length`` is written
+    to in the (L, NB, bs, …) pool; page NB (out of range, so the write
+    drops) where the slot's table leaves it unmapped."""
+    NB, bs = pool.shape[1], pool.shape[2]
     MB = block_tables.shape[1]
     ring = paging.paged_ring_active(cfg.sliding_window, bs, MB)
     lb = (length // bs).astype(jnp.int32)
     lb = (lb % ring) if ring else jnp.minimum(lb, MB - 1)
     off = (length % bs).astype(jnp.int32)
     blk = jnp.take_along_axis(block_tables, lb[:, None], axis=1)[:, 0]
-    safe = jnp.where(blk >= 0, blk, NB)  # NB is out of range -> dropped
-    k_pool = k_pool.at[safe, off].set(k_new[:, 0].astype(k_pool.dtype),
-                                      mode="drop")
-    v_pool = v_pool.at[safe, off].set(v_new[:, 0].astype(v_pool.dtype),
-                                      mode="drop")
-    return q, k_pool, v_pool
+    return jnp.where(blk >= 0, blk, NB), off
 
 
 def _attn_step_paged(lp, cfg: ModelConfig, u1, k_pool, v_pool, ctx):
-    """Registered backend ("paged", "generic"): decode step vs a paged
-    pool.  u1 (B,1,d); k_pool/v_pool (NB,bs,Hkv,Dh).  Returns (cat,
-    new_k_pool, new_v_pool)."""
-    B, length = u1.shape[0], ctx["length"]
+    """Registered backend ("paged", "generic"): decode step vs layer
+    ``ctx["layer"]`` of a paged pool.  u1 (B,1,d); k_pool/v_pool the whole
+    (L,NB,bs,Hkv,Dh) stacks.  Returns (cat, new_k_pool, new_v_pool)."""
+    B, length, layer = u1.shape[0], ctx["length"], ctx["layer"]
     block_tables = ctx["block_tables"]
     merged = _is_merged(cfg.block_style)
     q, k_new, v_new = _project_qkv(lp, cfg, u1, u1, merged)
     q, k_pool, v_pool = _rope_and_insert_paged(cfg, q, k_new, v_new,
                                                k_pool, v_pool, block_tables,
-                                               length)
+                                               length, layer)
     out = attn_mod.decode_attention_core_paged(
         q[:, 0], k_pool, v_pool, block_tables=block_tables,
-        q_position=length, sliding_window=cfg.sliding_window,
+        q_position=length, layer=layer, sliding_window=cfg.sliding_window,
         impl=ctx["impl"])
     return out.reshape(B, 1, cfg.attn_dim), k_pool, v_pool
 
@@ -1550,17 +1575,17 @@ def _attn_step_paged_merged(lp, cfg: ModelConfig, u1, k_pool, v_pool, ctx):
     vs a paged pool — per token the attention-side HBM traffic is K*/V*
     weights plus the slot's mapped pages: no Q/P weight reads AND no dense
     worst-case-length cache."""
-    B, length = u1.shape[0], ctx["length"]
+    B, length, layer = u1.shape[0], ctx["length"], ctx["layer"]
     block_tables = ctx["block_tables"]
     # variant "qp": _project_qkv returns the stream itself as q (identity)
     q, k_new, v_new = _project_qkv(lp, cfg, u1, u1, True)
     q, k_new, v_new = _qkv_reanchor(ctx, q, k_new, v_new)
     q, k_pool, v_pool = _rope_and_insert_paged(cfg, q, k_new, v_new,
                                                k_pool, v_pool, block_tables,
-                                               length)
+                                               length, layer)
     out = attn_mod.decode_attention_core_paged_merged(
         q.reshape(B, cfg.attn_dim), k_pool, v_pool,
-        block_tables=block_tables, q_position=length,
+        block_tables=block_tables, q_position=length, layer=layer,
         n_kv_heads=cfg.n_kv_heads, sliding_window=cfg.sliding_window,
         impl=ctx["impl"])
     return out.reshape(B, 1, cfg.attn_dim), k_pool, v_pool
@@ -1568,38 +1593,33 @@ def _attn_step_paged_merged(lp, cfg: ModelConfig, u1, k_pool, v_pool, ctx):
 
 def _rope_and_insert_paged_q8(cfg: ModelConfig, q, k_new, v_new,
                               k_pool, v_pool, k_scale, v_scale,
-                              block_tables, length):
+                              block_tables, length, layer):
     """``_rope_and_insert_paged`` over int8 pools: RoPE, then QUANTIZE the
-    new token into each slot's mapped page under the page's monotone
-    scale merge (``kernels.quant.q8_append_token`` — the scale row is
-    written in the same drop-scatter as the page bytes).  Runs in plain
-    XLA inside every impl's program, so pool bits are impl-independent."""
+    new token into layer ``layer`` of each slot's mapped page under the
+    page's monotone scale merge (``kernels.quant.q8_append_token`` — the
+    scale row is written in the same drop-scatter as the page bytes).
+    Runs in plain XLA inside every impl's program, so pool bits are
+    impl-independent."""
     pos = length[:, None]  # (B,1)
     q = apply_rope(q, pos, style=cfg.rope_style, theta=cfg.rope_theta,
                    fraction=cfg.rope_fraction)
     k_new = apply_rope(k_new, pos, style=cfg.rope_style, theta=cfg.rope_theta,
                        fraction=cfg.rope_fraction)
-    NB, bs = k_pool.shape[0], k_pool.shape[1]
-    MB = block_tables.shape[1]
-    ring = paging.paged_ring_active(cfg.sliding_window, bs, MB)
-    lb = (length // bs).astype(jnp.int32)
-    lb = (lb % ring) if ring else jnp.minimum(lb, MB - 1)
-    off = (length % bs).astype(jnp.int32)
-    blk = jnp.take_along_axis(block_tables, lb[:, None], axis=1)[:, 0]
-    safe = jnp.where(blk >= 0, blk, NB)  # NB is out of range -> dropped
+    safe, off = _paged_write_slot(cfg, k_pool, block_tables, length)
     k_pool, k_scale = quant.q8_append_token(k_pool, k_scale, k_new[:, 0],
-                                            safe, off)
+                                            safe, off, layer)
     v_pool, v_scale = quant.q8_append_token(v_pool, v_scale, v_new[:, 0],
-                                            safe, off)
+                                            safe, off, layer)
     return q, k_pool, v_pool, k_scale, v_scale
 
 
 def _attn_step_paged_q8(lp, cfg: ModelConfig, u1, k_store, v_store, ctx):
-    """Registered backend ("paged_q8", "generic"): decode step vs the
-    quantized pool.  The scan-carried stores are (pool, scale) pytree
-    pairs — ``apply_block_step`` treats them opaquely, so the block wiring
-    is untouched.  Returns (cat, (k_pool, k_scale), (v_pool, v_scale))."""
-    B, length = u1.shape[0], ctx["length"]
+    """Registered backend ("paged_q8", "generic"): decode step vs layer
+    ``ctx["layer"]`` of the quantized pool.  The scan-carried stores are
+    whole (pool, scale) stacks in pytree pairs — ``apply_block_step``
+    treats them opaquely, so the block wiring is untouched.  Returns
+    (cat, (k_pool, k_scale), (v_pool, v_scale))."""
+    B, length, layer = u1.shape[0], ctx["length"], ctx["layer"]
     block_tables = ctx["block_tables"]
     k_pool, k_scale = k_store
     v_pool, v_scale = v_store
@@ -1607,10 +1627,10 @@ def _attn_step_paged_q8(lp, cfg: ModelConfig, u1, k_store, v_store, ctx):
     q, k_new, v_new = _project_qkv(lp, cfg, u1, u1, merged)
     q, k_pool, v_pool, k_scale, v_scale = _rope_and_insert_paged_q8(
         cfg, q, k_new, v_new, k_pool, v_pool, k_scale, v_scale,
-        block_tables, length)
+        block_tables, length, layer)
     out = attn_mod.decode_attention_core_paged_q8(
         q[:, 0], k_pool, v_pool, k_scale, v_scale,
-        block_tables=block_tables, q_position=length,
+        block_tables=block_tables, q_position=length, layer=layer,
         sliding_window=cfg.sliding_window, impl=ctx["impl"])
     return out.reshape(B, 1, cfg.attn_dim), (k_pool, k_scale), \
         (v_pool, v_scale)
@@ -1623,7 +1643,7 @@ def _attn_step_paged_q8_merged(lp, cfg: ModelConfig, u1, k_store, v_store,
     is K*/V* weights plus ONE BYTE per mapped pooled element (the pallas
     kernel dequantizes per tile in VMEM; no full-precision pool view is
     ever materialized)."""
-    B, length = u1.shape[0], ctx["length"]
+    B, length, layer = u1.shape[0], ctx["length"], ctx["layer"]
     block_tables = ctx["block_tables"]
     k_pool, k_scale = k_store
     v_pool, v_scale = v_store
@@ -1632,10 +1652,10 @@ def _attn_step_paged_q8_merged(lp, cfg: ModelConfig, u1, k_store, v_store,
     q, k_new, v_new = _qkv_reanchor(ctx, q, k_new, v_new)
     q, k_pool, v_pool, k_scale, v_scale = _rope_and_insert_paged_q8(
         cfg, q, k_new, v_new, k_pool, v_pool, k_scale, v_scale,
-        block_tables, length)
+        block_tables, length, layer)
     out = attn_mod.decode_attention_core_paged_q8_merged(
         q.reshape(B, cfg.attn_dim), k_pool, v_pool, k_scale, v_scale,
-        block_tables=block_tables, q_position=length,
+        block_tables=block_tables, q_position=length, layer=layer,
         n_kv_heads=cfg.n_kv_heads, sliding_window=cfg.sliding_window,
         impl=ctx["impl"])
     return out.reshape(B, 1, cfg.attn_dim), (k_pool, k_scale), \
@@ -1743,25 +1763,31 @@ def _chunk_last_logits(logits, start, true_len, C):
     return jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0, :]
 
 
-def _chunk_block_scan(params, cfg: ModelConfig, h, chunk_attn, k_stack,
-                      v_stack, impl, qkv_sharding):
-    """Run the block stack over a chunk stream, scanning per-layer KV
-    stores exactly as ``forward_step`` does, with ``chunk_attn`` as the
-    attention route (``apply_block_step``'s backend seam is shape-agnostic
-    in the stream's sequence extent, so the whole block wiring — styles,
-    b_out, norms, FFN — is reused as-is)."""
-    ctx = {"impl": impl, "qkv_sharding": qkv_sharding,
-           "backend": backends.AttentionBackend(
-               cache_kind="chunk", style="chunk", impl=impl,
-               step=chunk_attn)}
-
+def _scan_layer_slices(params, cfg: ModelConfig, h, stores, ctx):
+    """Run the attention-only block stack with each layer's slice of the
+    (L, …) stores as the scan's xs, the updated slices stacked as its ys
+    (the dense cache's route).  Returns (h, new stores)."""
     def f(hh, xs):
         lp, lc = xs
         out, nc = apply_block_step(lp, cfg, "attn", hh, lc, ctx)
         return out, nc
 
-    h, ncs = jax.lax.scan(f, h, (params["layers"],
-                                 {"k": k_stack, "v": v_stack}))
+    return jax.lax.scan(f, h, (params["layers"], stores))
+
+
+def _chunk_block_scan(params, cfg: ModelConfig, h, chunk_attn, k_stack,
+                      v_stack, impl, qkv_sharding, scan_layers):
+    """Run the block stack over a chunk stream with ``chunk_attn`` as the
+    attention route, by ``scan_layers`` — ``_scan_layer_slices`` (dense)
+    or ``_scan_pool_layers`` (paged), as ``forward_step`` scans its cache
+    (``apply_block_step``'s backend seam is shape-agnostic in the stream's
+    sequence extent, so the whole block wiring — styles, b_out, norms,
+    FFN — is reused as-is)."""
+    ctx = {"impl": impl, "qkv_sharding": qkv_sharding,
+           "backend": backends.AttentionBackend(
+               cache_kind="chunk", style="chunk", impl=impl,
+               step=chunk_attn)}
+    h, ncs = scan_layers(params, cfg, h, {"k": k_stack, "v": v_stack}, ctx)
     if "final_norm" in params:
         h = apply_rmsnorm(params["final_norm"], h)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
@@ -1836,7 +1862,8 @@ def _chunk_dense(params, cfg: ModelConfig, chunk, dest, ctx, *,
     h = embed_inputs(params, cfg, chunk)
     logits, ncs = _chunk_block_scan(params, cfg, h, chunk_attn,
                                     cache.k, cache.v, impl,
-                                    ctx.get("qkv_sharding"))
+                                    ctx.get("qkv_sharding"),
+                                    _scan_layer_slices)
     last = _chunk_last_logits(logits, start, true_len, C)
     new_len = cache.length.at[s0].set(jnp.minimum(p0 + C, true_len[0]))
     return last, cache._replace(k=ncs["k"], v=ncs["v"], kv_pos=kv_pos,
@@ -1845,11 +1872,13 @@ def _chunk_dense(params, cfg: ModelConfig, chunk, dest, ctx, *,
 
 def _chunk_paged(params, cfg: ModelConfig, chunk, dest, ctx, *,
                  merged_core: bool):
-    """Shared body of both paged chunk routes: write the chunk's pages
-    (drop-scatter, ``PagedChunkDest.block_ids`` contract), then attend
-    over the slot's densified page view.  The gather materializes a
-    (1, MB*bs) view per layer — same extent as the XLA paged decode core;
-    a fused chunk kernel that walks the table is the follow-up
+    """Shared body of both paged chunk routes: per layer, write the
+    chunk's pages into that layer of the carried pool stack (drop-scatter
+    at ``[layer, block_ids]``, ``PagedChunkDest.block_ids`` contract),
+    then attend over the slot's densified page view of the layer.  The
+    gather materializes a (1, MB*bs) view per layer — same extent as the
+    XLA paged decode core; a fused chunk kernel that walks the table is
+    the follow-up
     (``NoOversizedBuffer`` deliberately does not cover the chunk phase).
     Ring (sliding-window) tables: the dispatcher pins C == block_size, so
     the whole chunk lives in one ring slot and position reconstruction at
@@ -1870,15 +1899,16 @@ def _chunk_paged(params, cfg: ModelConfig, chunk, dest, ctx, *,
     safe = jnp.where(bids >= 0, bids, NB).astype(jnp.int32)  # (nbk,)
 
     def chunk_attn(lp, cfg_, x, kp, vp, actx):
+        layer = actx["layer"]
         q, k_new, v_new = _project_qkv(lp, cfg_, x, x, merged)
         q, k_new, v_new = _qkv_reanchor(actx, q, k_new, v_new)
         q, k_new = _chunk_rope(cfg_, q, k_new, pos)
         kb = k_new[0].astype(kp.dtype).reshape(nbk, bs, Hkv, Dh)
         vb = v_new[0].astype(vp.dtype).reshape(nbk, bs, Hkv, Dh)
-        kp = kp.at[safe].set(kb, mode="drop")
-        vp = vp.at[safe].set(vb, mode="drop")
-        gk = attn_mod._paged_gather(kp, table)  # (1, MB*bs, Hkv, Dh)
-        gv = attn_mod._paged_gather(vp, table)
+        kp = kp.at[layer, safe].set(kb, mode="drop")
+        vp = vp.at[layer, safe].set(vb, mode="drop")
+        gk = attn_mod._paged_gather(kp, table, layer)  # (1, MB*bs, Hkv, Dh)
+        gv = attn_mod._paged_gather(vp, table, layer)
         if merged_core:
             out = attn_mod.attention_core_merged(
                 q.reshape(1, C, cfg_.attn_dim), gk, gv,
@@ -1896,7 +1926,8 @@ def _chunk_paged(params, cfg: ModelConfig, chunk, dest, ctx, *,
     h = embed_inputs(params, cfg, chunk)
     logits, ncs = _chunk_block_scan(params, cfg, h, chunk_attn,
                                     k_pool, v_pool, impl,
-                                    ctx.get("qkv_sharding"))
+                                    ctx.get("qkv_sharding"),
+                                    _scan_pool_layers)
     last = _chunk_last_logits(logits, start, true_len, C)
     return last, (ncs["k"], ncs["v"])
 
@@ -1909,8 +1940,8 @@ def _chunk_paged_q8(params, cfg: ModelConfig, chunk, dest, ctx, *,
     ``quant.q8_quantize_seq`` call the whole-prompt q8 prefill fake-quants
     with, so a chunked prompt lands bit-identical pool bytes) and the
     attention view is the DEQUANTIZED page gather, matching what decode's
-    q8 cores reconstruct.  Scan-carried stores are (pool, scale) pairs,
-    exactly as in the q8 decode step."""
+    q8 cores reconstruct.  Scan-carried stores are whole (pool, scale)
+    stacks in pairs, exactly as in the q8 decode step."""
     k_pool, v_pool, k_scale, v_scale, table, bids = dest
     start, true_len = ctx["start"], ctx["true_len"]
     impl = ctx.get("impl", "xla")
@@ -1928,6 +1959,7 @@ def _chunk_paged_q8(params, cfg: ModelConfig, chunk, dest, ctx, *,
     valid = pos < true_len[:, None]  # (1,C)
 
     def chunk_attn(lp, cfg_, x, kst, vst, actx):
+        layer = actx["layer"]
         kp, ks = kst
         vp, vs = vst
         q, k_new, v_new = _project_qkv(lp, cfg_, x, x, merged)
@@ -1935,12 +1967,14 @@ def _chunk_paged_q8(params, cfg: ModelConfig, chunk, dest, ctx, *,
         q, k_new = _chunk_rope(cfg_, q, k_new, pos)
         kq, ksc = quant.q8_quantize_seq(k_new, bs, valid)
         vq, vsc = quant.q8_quantize_seq(v_new, bs, valid)
-        kp = kp.at[safe].set(kq[0].reshape(nbk, bs, Hkv, Dh), mode="drop")
-        vp = vp.at[safe].set(vq[0].reshape(nbk, bs, Hkv, Dh), mode="drop")
-        ks = ks.at[safe].set(ksc[0], mode="drop")
-        vs = vs.at[safe].set(vsc[0], mode="drop")
-        gk = attn_mod._paged_gather_q8(kp, ks, table, x.dtype)
-        gv = attn_mod._paged_gather_q8(vp, vs, table, x.dtype)
+        kp = kp.at[layer, safe].set(kq[0].reshape(nbk, bs, Hkv, Dh),
+                                    mode="drop")
+        vp = vp.at[layer, safe].set(vq[0].reshape(nbk, bs, Hkv, Dh),
+                                    mode="drop")
+        ks = ks.at[layer, safe].set(ksc[0], mode="drop")
+        vs = vs.at[layer, safe].set(vsc[0], mode="drop")
+        gk = attn_mod._paged_gather_q8(kp, ks, table, layer, x.dtype)
+        gv = attn_mod._paged_gather_q8(vp, vs, table, layer, x.dtype)
         if merged_core:
             out = attn_mod.attention_core_merged(
                 q.reshape(1, C, cfg_.attn_dim), gk, gv,
@@ -1958,7 +1992,8 @@ def _chunk_paged_q8(params, cfg: ModelConfig, chunk, dest, ctx, *,
     h = embed_inputs(params, cfg, chunk)
     logits, ncs = _chunk_block_scan(params, cfg, h, chunk_attn,
                                     (k_pool, k_scale), (v_pool, v_scale),
-                                    impl, ctx.get("qkv_sharding"))
+                                    impl, ctx.get("qkv_sharding"),
+                                    _scan_pool_layers)
     last = _chunk_last_logits(logits, start, true_len, C)
     return last, (ncs["k"][0], ncs["v"][0], ncs["k"][1], ncs["v"][1])
 

@@ -1,5 +1,6 @@
 """Every Pallas kernel, and a whole paged decode step, compiles for a TPU
-v5e at real widths.
+v5e at real widths; the paged programs read and write the pool stack in
+place.
 
 These compile for a described (not attached) ``v5e:2x2`` topology with
 the TPU compiler that ships with jax, so they run on a machine without a
@@ -10,6 +11,7 @@ topology is described only inside the module fixture, never at import
 where it cannot be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from repro.serving import Engine, ServeConfig
 # page; mamba2-2.7b widths for the SSD scan (80 heads of 64, state 128)
 B, HQ, HKV, D, PAGE = 4, 32, 8, 128, 16
 NB, MB, S_DENSE, S_PREFILL = 512, 64, 1024, 512
+L_POOL = 2  # layers of the stacked pool a paged kernel reads one of
 BF, I32, F32, I8 = jnp.bfloat16, jnp.int32, jnp.float32, jnp.int8
 
 
@@ -56,7 +59,11 @@ def _compile(fn, args):
 
 
 def _pool(dt=BF):
-    return [(NB, PAGE, HKV, D), dt]
+    return [(L_POOL, NB, PAGE, HKV, D), dt]
+
+
+_SCALES = [(L_POOL, NB, HKV), F32]
+_LAYER = [(), I32]
 
 
 KERNEL_CASES = {
@@ -71,24 +78,29 @@ KERNEL_CASES = {
         [[(B, HQ * D)], [(B, S_DENSE, HKV, D)], [(B, S_DENSE, HKV, D)],
          [(B, S_DENSE), I32], [(B,), I32]]),
     "decode_attention_paged": (
-        lambda q, k, v, bt, qp: ops.decode_attention_paged(
-            q, k, v, block_tables=bt, q_position=qp),
-        [[(B, HQ, D)], _pool(), _pool(), [(B, MB), I32], [(B,), I32]]),
+        lambda q, k, v, bt, qp, li: ops.decode_attention_paged(
+            q, k, v, block_tables=bt, q_position=qp, layer=li),
+        [[(B, HQ, D)], _pool(), _pool(), [(B, MB), I32], [(B,), I32],
+         _LAYER]),
     "decode_attention_paged_merged": (
-        lambda u, k, v, bt, qp: ops.decode_attention_paged_merged(
-            u, k, v, block_tables=bt, q_position=qp, n_kv_heads=HKV),
-        [[(B, HQ * D)], _pool(), _pool(), [(B, MB), I32], [(B,), I32]]),
-    "decode_attention_paged_q8": (
-        lambda q, k, v, ks, vs, bt, qp: ops.decode_attention_paged_q8(
-            q, k, v, k_scale=ks, v_scale=vs, block_tables=bt, q_position=qp),
-        [[(B, HQ, D)], _pool(I8), _pool(I8), [(NB, HKV), F32],
-         [(NB, HKV), F32], [(B, MB), I32], [(B,), I32]]),
-    "decode_attention_paged_q8_merged": (
-        lambda u, k, v, ks, vs, bt, qp: ops.decode_attention_paged_q8_merged(
-            u, k, v, k_scale=ks, v_scale=vs, block_tables=bt, q_position=qp,
+        lambda u, k, v, bt, qp, li: ops.decode_attention_paged_merged(
+            u, k, v, block_tables=bt, q_position=qp, layer=li,
             n_kv_heads=HKV),
-        [[(B, HQ * D)], _pool(I8), _pool(I8), [(NB, HKV), F32],
-         [(NB, HKV), F32], [(B, MB), I32], [(B,), I32]]),
+        [[(B, HQ * D)], _pool(), _pool(), [(B, MB), I32], [(B,), I32],
+         _LAYER]),
+    "decode_attention_paged_q8": (
+        lambda q, k, v, ks, vs, bt, qp, li: ops.decode_attention_paged_q8(
+            q, k, v, k_scale=ks, v_scale=vs, block_tables=bt, q_position=qp,
+            layer=li),
+        [[(B, HQ, D)], _pool(I8), _pool(I8), _SCALES, _SCALES,
+         [(B, MB), I32], [(B,), I32], _LAYER]),
+    "decode_attention_paged_q8_merged": (
+        lambda u, k, v, ks, vs, bt, qp, li:
+        ops.decode_attention_paged_q8_merged(
+            u, k, v, k_scale=ks, v_scale=vs, block_tables=bt, q_position=qp,
+            layer=li, n_kv_heads=HKV),
+        [[(B, HQ * D)], _pool(I8), _pool(I8), _SCALES, _SCALES,
+         [(B, MB), I32], [(B,), I32], _LAYER]),
     "flash_attention": (
         lambda q, k, v: ops.flash_attention(q, k, v, sliding_window=4096),
         [[(1, S_PREFILL, HQ, D)], [(1, S_PREFILL, HKV, D)],
@@ -120,9 +132,9 @@ B_SERVE, MB_SERVE, NB_SERVE = 32, 128, 3072
 
 def _serve_width(specs):
     widths = {(B, HQ, D): (B_SERVE, HQ, D), (B, HQ * D): (B_SERVE, HQ * D),
-              (B, MB): (B_SERVE, MB_SERVE), (B,): (B_SERVE,),
-              (NB, PAGE, HKV, D): (NB_SERVE, PAGE, HKV, D),
-              (NB, HKV): (NB_SERVE, HKV)}
+              (B, MB): (B_SERVE, MB_SERVE), (B,): (B_SERVE,), (): (),
+              (L_POOL, NB, PAGE, HKV, D): (L_POOL, NB_SERVE, PAGE, HKV, D),
+              (L_POOL, NB, HKV): (L_POOL, NB_SERVE, HKV)}
     return [[widths[tuple(shape)], *dt] for shape, *dt in specs]
 
 
@@ -147,22 +159,78 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     _compile(fn, args)
 
 
+def _on_chip(tree, sharding):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sharding), tree)
+
+
+def _paged_engine(one_chip, style, sc):
+    """A paged Pallas ``Engine`` over 2 layers at Mistral-7B widths with
+    bf16 weights, and its weights' shapes on the described chip."""
+    cfg = get_config("mistral-7b").with_(n_layers=2, param_dtype="bfloat16",
+                                         block_style=style)
+    params = _on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    return Engine(cfg, params, sc, impl="pallas", cache="paged"), params
+
+
 @pytest.mark.parametrize("style", ["standard", "skipless_merged"])
 def test_paged_pallas_decode_step_compiles_for_v5e(one_chip, style):
     """The engine's whole serve_step (2 layers at Mistral-7B widths, bf16
     weights, paged pool) with the Pallas decode kernel inside."""
-    cfg = get_config("mistral-7b").with_(n_layers=2, param_dtype="bfloat16",
-                                         block_style=style)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
-    params = on_chip(jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg)))
-    eng = Engine(cfg, params, ServeConfig(n_slots=B, max_len=512),
-                 impl="pallas", cache="paged")
+    eng, params = _paged_engine(one_chip, style,
+                                ServeConfig(n_slots=B, max_len=512))
     assert eng.merged_fast_path == (style == "skipless_merged")
     tokens = jax.ShapeDtypeStruct((B,), I32, sharding=one_chip)
-    compiled = eng._decode.lower(params, tokens, on_chip(eng.kv.spec()))
+    compiled = eng._decode.lower(params, tokens,
+                                 _on_chip(eng.kv.spec(), one_chip))
     assert "tpu_custom_call" in compiled.compile().as_text()
+
+
+# one layer's pool of the benchmark's engine: 3,072 pages of 16 tokens of
+# 8 kv heads of 128, bf16 (100.7 MB); ops of that shape, of (1, …) that
+# shape, or of the whole stack, are the pool copied or sliced per layer
+POOL_LAYER_BYTES = NB_SERVE * PAGE * HKV * D * 2
+_POOL_OP = re.compile(r"= \w+\[([\d,]*)\]\S* "
+                      r"(copy|copy-start|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _pool_sized_ops(hlo: str, n_layers: int):
+    layer = f"{NB_SERVE},{PAGE},{HKV},{D}"
+    sized = {layer, f"1,{layer}", f"{n_layers},{layer}"}
+    return sorted({f"{op} {shape}" for shape, op in _POOL_OP.findall(hlo)
+                   if shape in sized})
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("style", ["standard", "skipless_merged"])
+def test_paged_programs_address_the_pool_stack_in_place(one_chip, style,
+                                                        program):
+    """The engine's decode step and its 512-token paged chunk program at
+    the benchmark's engine (Mistral-7B widths, 2 layers, 32 slots,
+    max_len 2,048, 3,072 pages) compile with no copy, slice or
+    update-slice of one layer's pool or of the stack, and with less
+    scratch than one layer's pool.  The chunk program's scratch holds, by
+    design, its XLA attention's float32 scores (512 queries x 2,048 keys
+    x 32 heads, 134 MB) beside that bound."""
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    eng, params = _paged_engine(
+        one_chip, style,
+        ServeConfig(n_slots=B_SERVE, max_len=2048, n_blocks=NB_SERVE))
+    cache = _on_chip(eng.kv.spec(), one_chip)
+    n_layers = cache.k.shape[0]
+    assert cache.k.shape == (n_layers, NB_SERVE, PAGE, HKV, D)
+    bound = POOL_LAYER_BYTES
+    if program == "decode":
+        compiled = eng._decode.lower(params, i32(B_SERVE), cache).compile()
+    else:
+        C = 512
+        eng.kv.build_chunk(C, "pallas")
+        compiled = eng.kv._chunk.lower(
+            params, i32(1, C), i32(1), i32(1), cache.k, cache.v,
+            i32(1, MB_SERVE), i32(C // PAGE)).compile()
+        bound += C * MB_SERVE * PAGE * HQ * 4
+    assert _pool_sized_ops(compiled.as_text(), n_layers) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < bound

@@ -13,7 +13,8 @@ from repro.configs import get_config, reduce_config
 from repro.lint import (DonationEffective, Finding, LintRule, LintTarget,
                         NoDequantizedPoolBuffer, NoDtypePromotionDrift,
                         NoForbiddenMatmul, NoHostTransferInObsHooks,
-                        NoHostTransferInStepLoop, NoOversizedBuffer, aliasing,
+                        NoHostTransferInStepLoop, NoOversizedBuffer,
+                        NoPerLayerPoolSlice, aliasing,
                         get_rule, register_rule, registered_rules, run_rules,
                         sweep, walker)
 from repro.lint.builtin import HOST_TRANSFER_PRIMITIVES
@@ -204,6 +205,53 @@ def test_no_dequantized_pool_buffer_fires_on_fp32_shadow():
     # rule is scoped to paged_q8 programs only
     assert not NoDequantizedPoolBuffer().applies(
         _target(cache_kind="paged", cache_shapes=(shape,)))
+
+
+def test_no_per_layer_pool_slice_fires_on_sliced_layer_scan(sweep_report):
+    """A layer scan that takes each layer's pool as its xs and stacks the
+    written pools as its ys (the paged programs' old scan) holds one
+    layer's (NB, bs, Hkv, D) pool and fires, at any dtype; the same scan
+    carrying the stack and writing at ``[layer, page, offset]`` is quiet,
+    and so is every paged decode and chunk program of the sweep."""
+    shape = (3, 6, 8, 2, 4)  # (L, n_blocks, block, heads, d_head)
+    h0 = jnp.ones((2, 4), jnp.bfloat16)
+
+    def sliced(pools):
+        def f(h, pool):
+            return h, pool.at[1, 0].set(h.astype(pool.dtype))
+        return jax.lax.scan(f, h0, pools)[1]
+
+    def carried(pools):
+        def f(st, layer):
+            return st.at[layer, 1, 0].set(h0.astype(st.dtype)), None
+        return jax.lax.scan(f, pools, jnp.arange(shape[0]))[0]
+
+    for dtype in (jnp.bfloat16, jnp.int8):
+        pools = jnp.zeros(shape, dtype)
+        for phase, kind in (("decode", "paged"), ("chunk", "paged_q8")):
+            def target(fn):
+                return _target(phase=phase, cache_kind=kind,
+                               cache_shapes=(shape,),
+                               jaxpr=jax.make_jaxpr(fn)(pools))
+
+            findings = NoPerLayerPoolSlice().check(target(sliced))
+            assert findings and findings[0].rule == "NoPerLayerPoolSlice"
+            assert [6, 8, 2, 4] in [h[0] for h in findings[0].detail["hits"]]
+            assert NoPerLayerPoolSlice().check(target(carried)) == []
+    # scoped to the paged decode and chunk programs
+    assert not NoPerLayerPoolSlice().applies(
+        _target(phase="prefill", cache_kind="paged", cache_shapes=(shape,)))
+    assert not NoPerLayerPoolSlice().applies(
+        _target(phase="decode", cache_kind="dense", cache_shapes=(shape,)))
+    paged = [t for t in sweep_report.targets
+             if t.phase in ("decode", "chunk")
+             and t.cache_kind in ("paged", "paged_q8")]
+    assert {(t.phase, t.cache_kind) for t in paged} == {
+        (p, k) for p in ("decode", "chunk") for k in ("paged", "paged_q8")}
+    for t in paged:
+        assert "NoPerLayerPoolSlice" in t.rules_run, t.key
+        assert not [f for f in t.findings
+                    if f.rule == "NoPerLayerPoolSlice"], t.key
 
 
 def test_host_transfer_fires_on_debug_print_in_step():

@@ -12,7 +12,8 @@ from repro.configs import get_config, reduce_config
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import pages_per_block
 from repro.kernels.paging import paged_ring_active
-from repro.models import forward_seq, init_params
+from repro.models import (DecodeCache, PagedDecodeCache, PagedQ8DecodeCache,
+                          forward_seq, forward_step, init_params)
 from repro.serving import Engine, ServeConfig
 from repro.serving.paged_kv_cache import BlockAllocator, PagedCacheManager
 
@@ -60,6 +61,29 @@ NB_4P, NB_RAGGED = 3 * MB_4P, 3 * MB_RAGGED  # pages for all six slots
 SHIELD = (5,)
 
 
+# the kernels read one layer of a stacked (L, NB, bs, Hkv, D) pool: each
+# case reads the last of three layers under its own id, and the first
+# under its id + "-layer0"; the other layers hold other draws
+N_LAYERS, LAST = 3, 2
+
+
+def _each_layer(cases):
+    return [pytest.param(*c.values, layer, marks=c.marks,
+                         id=c.id if layer == LAST else f"{c.id}-layer0")
+            for c in cases for layer in (LAST, 0)]
+
+
+def _stack(pool, layer, key):
+    """``pool`` as layer ``layer`` of an N_LAYERS stack whose other layers
+    hold other draws of the same kind, so a read of another layer shows."""
+    shape = (N_LAYERS, *pool.shape)
+    if pool.dtype == jnp.int8:
+        others = jax.random.randint(key, shape, -127, 128, jnp.int32)
+    else:
+        others = jax.random.normal(key, shape, jnp.float32) * 2.0 + 0.5
+    return others.astype(pool.dtype).at[layer].set(pool)
+
+
 def _close(out, want, dtype):
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -67,7 +91,8 @@ def _close(out, want, dtype):
                                atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("dtype,B,Hq,Hkv,D,NB,bs,MB,win,lens,shielded", [
+@pytest.mark.parametrize(
+    "dtype,B,Hq,Hkv,D,NB,bs,MB,win,lens,shielded,layer", _each_layer([
     pytest.param(jnp.float32, 3, 4, 2, 64, 16, 16, 4, 0, None, (),
                  id="float32-3-4-2-64-16-16-4-0"),
     pytest.param(jnp.bfloat16, 3, 4, 2, 64, 16, 16, 4, 0, None, (),
@@ -84,21 +109,22 @@ def _close(out, want, dtype):
                  EDGES_RAGGED, SHIELD, id="block-edges-ragged"),
     pytest.param(jnp.float32, 6, 8, 2, 32, NB_4P, 16, MB_4P, 100, EDGES_4P,
                  (), id="block-edges-MB4P-window100"),
-])
+]))
 def test_paged_decode_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win,
-                                         lens, shielded):
+                                         lens, shielded, layer):
     rng = np.random.RandomState(0)
     G = Hq // Hkv
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
     kp = jax.random.normal(ks[1], (NB, bs, Hkv, D), dtype)
     vp = jax.random.normal(ks[2], (NB, bs, Hkv, D), dtype)
     lens = _lens(rng, lens, B, MB, bs)
     bt = _rand_tables(rng, B, MB, NB, bs, lens, shielded)
     qpos = jnp.asarray(lens, jnp.int32)
-    out = ops.decode_attention_paged(q, kp, vp, block_tables=bt,
-                                     q_position=qpos, sliding_window=win,
-                                     interpret=True)
+    out = ops.decode_attention_paged(
+        q, _stack(kp, layer, ks[3]), _stack(vp, layer, ks[4]),
+        block_tables=bt, q_position=qpos, layer=layer, sliding_window=win,
+        interpret=True)
     want = ref.ref_decode_attention_paged(
         q.reshape(B, Hkv, G, D), kp, vp, bt, qpos,
         sliding_window=win).reshape(B, Hq, D)
@@ -107,7 +133,8 @@ def test_paged_decode_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win,
         assert not np.any(np.asarray(out[b], np.float32))
 
 
-@pytest.mark.parametrize("dtype,B,Hq,Hkv,D,NB,bs,MB,win,lens,shielded", [
+@pytest.mark.parametrize(
+    "dtype,B,Hq,Hkv,D,NB,bs,MB,win,lens,shielded,layer", _each_layer([
     pytest.param(jnp.float32, 3, 4, 2, 64, 16, 16, 4, 0, None, (),
                  id="float32-3-4-2-64-16-16-4-0"),
     pytest.param(jnp.bfloat16, 2, 4, 1, 32, 12, 8, 4, 0, None, (),  # MQA
@@ -118,11 +145,11 @@ def test_paged_decode_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win,
                  SHIELD, id="block-edges-MB4P-bfloat16"),
     pytest.param(jnp.float32, 6, 8, 2, 32, NB_RAGGED, 16, MB_RAGGED, 0,
                  EDGES_RAGGED, SHIELD, id="block-edges-ragged"),
-])
+]))
 def test_paged_merged_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win,
-                                         lens, shielded):
+                                         lens, shielded, layer):
     rng = np.random.RandomState(1)
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
     u = jax.random.normal(ks[0], (B, Hq * D), dtype)
     kp = jax.random.normal(ks[1], (NB, bs, Hkv, D), dtype)
     vp = jax.random.normal(ks[2], (NB, bs, Hkv, D), dtype)
@@ -130,7 +157,8 @@ def test_paged_merged_kernel_matches_ref(dtype, B, Hq, Hkv, D, NB, bs, MB, win,
     bt = _rand_tables(rng, B, MB, NB, bs, lens, shielded)
     qpos = jnp.asarray(lens, jnp.int32)
     out = ops.decode_attention_paged_merged(
-        u, kp, vp, block_tables=bt, q_position=qpos, n_kv_heads=Hkv,
+        u, _stack(kp, layer, ks[3]), _stack(vp, layer, ks[4]),
+        block_tables=bt, q_position=qpos, layer=layer, n_kv_heads=Hkv,
         sliding_window=win, interpret=True)
     want = ref.ref_decode_attention_paged_merged(
         u, kp, vp, bt, qpos, n_kv_heads=Hkv, sliding_window=win)
@@ -158,18 +186,21 @@ def _ring_tables(rng, B, R, NB, bs, lens):
 
 
 @pytest.mark.parametrize("style", ["generic", "merged"])
-@pytest.mark.parametrize("table", ["block-edges", "ring"])
-def test_paged_q8_kernel_matches_ref(style, table):
+@pytest.mark.parametrize("table,layer", _each_layer(
+    [pytest.param(t, id=t) for t in ("block-edges", "ring")]))
+def test_paged_q8_kernel_matches_ref(style, table, layer):
     """The int8 wrappers at the block edges (MB = 4P, one shielded slot)
     and on a ring-addressed table (window 400 < MB·bs: R = 26 slots, a
     full block and a ragged one), against the dequantize-then-attend
-    oracle."""
+    oracle on the layer read."""
     rng = np.random.RandomState(3)
     B, Hq, Hkv, D, NB, bs = 6, 8, 2, 32, NB_4P, 16
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(k1, (B, Hq, D), jnp.float32)
     (kp, ksc), (vp, vsc) = _q8_pool(k2, NB, bs, Hkv, D), \
         _q8_pool(k3, NB, bs, Hkv, D)
+    stacks = [_stack(a, layer, k) for a, k in
+              zip((kp, vp, ksc, vsc), jax.random.split(k4, 4))]
     if table == "ring":
         win, lens = 400, [1, 255, 257, 420, 1500, 3000]
         R = paged_ring_active(win, bs, 26)
@@ -179,26 +210,31 @@ def test_paged_q8_kernel_matches_ref(style, table):
         win, lens, R = 0, list(EDGES_4P), 0
         bt = _rand_tables(rng, B, MB_4P, NB, bs, lens, SHIELD)
     qpos = jnp.asarray(lens, jnp.int32)
+    k_st, v_st, ks_st, vs_st = stacks
     if style == "merged":
         out = ops.decode_attention_paged_q8_merged(
-            q.reshape(B, Hq * D), kp, vp, k_scale=ksc, v_scale=vsc,
-            block_tables=bt, q_position=qpos, n_kv_heads=Hkv,
+            q.reshape(B, Hq * D), k_st, v_st, k_scale=ks_st, v_scale=vs_st,
+            block_tables=bt, q_position=qpos, layer=layer, n_kv_heads=Hkv,
             sliding_window=win, interpret=True).reshape(B, Hq, D)
     else:
         out = ops.decode_attention_paged_q8(
-            q, kp, vp, k_scale=ksc, v_scale=vsc, block_tables=bt,
-            q_position=qpos, sliding_window=win, interpret=True)
+            q, k_st, v_st, k_scale=ks_st, v_scale=vs_st, block_tables=bt,
+            q_position=qpos, layer=layer, sliding_window=win,
+            interpret=True)
     want = ref.ref_decode_attention_paged_q8(
         q.reshape(B, Hkv, Hq // Hkv, D), kp, vp, ksc, vsc, bt, qpos,
         sliding_window=win, ring_blocks=R).reshape(B, Hq, D)
     _close(out, want, jnp.float32)
 
 
-@pytest.mark.parametrize("pool", ["bf16", "q8"])
-def test_paged_kernel_never_reads_unmapped_pages(pool):
-    """Every pool page no table maps holds NaN (its scales too, for an
-    int8 pool): the output is finite and equals the oracle's on the clean
-    pool, so a dead page never reaches the result."""
+@pytest.mark.parametrize("pool,layer", _each_layer(
+    [pytest.param(p, id=p) for p in ("bf16", "q8")]))
+def test_paged_kernel_never_reads_unmapped_pages(pool, layer):
+    """Every page of the layer read that no table maps holds NaN (its
+    scales too, for an int8 pool), and so does every other layer of the
+    stack (its scales, for an int8 pool): the output is finite and equals
+    the oracle's on the clean layer, so neither a dead page nor another
+    layer reaches the result."""
     rng = np.random.RandomState(4)
     B, Hq, Hkv, D, NB, bs, MB = 6, 8, 2, 32, NB_4P, 16, MB_4P
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(4), 3)
@@ -211,26 +247,34 @@ def test_paged_kernel_never_reads_unmapped_pages(pool):
     if pool == "q8":
         (kp, ksc), (vp, vsc) = _q8_pool(k2, NB, bs, Hkv, D), \
             _q8_pool(k3, NB, bs, Hkv, D)
-        nan_s = lambda s: jnp.where(dead[:, None], jnp.nan, s)  # noqa: E731
+        nan_s = lambda s: _nan_layers(  # noqa: E731
+            jnp.where(dead[:, None], jnp.nan, s), layer)
         out = ops.decode_attention_paged_q8(
-            q, kp, vp, k_scale=nan_s(ksc), v_scale=nan_s(vsc),
-            block_tables=bt, q_position=qpos, interpret=True)
+            q, _stack(kp, layer, k1), _stack(vp, layer, k1),
+            k_scale=nan_s(ksc), v_scale=nan_s(vsc), block_tables=bt,
+            q_position=qpos, layer=layer, interpret=True)
         want = ref.ref_decode_attention_paged_q8(
             q.reshape(B, Hkv, Hq // Hkv, D), kp, vp, ksc, vsc, bt, qpos)
     else:
         kp = jax.random.normal(k2, (NB, bs, Hkv, D), jnp.bfloat16)
         vp = jax.random.normal(k3, (NB, bs, Hkv, D), jnp.bfloat16)
-        nan_p = lambda p: jnp.where(  # noqa: E731
-            dead[:, None, None, None], jnp.nan, p).astype(p.dtype)
+        nan_p = lambda p: _nan_layers(jnp.where(  # noqa: E731
+            dead[:, None, None, None], jnp.nan, p).astype(p.dtype), layer)
         out = ops.decode_attention_paged(
             q.astype(jnp.bfloat16), nan_p(kp), nan_p(vp), block_tables=bt,
-            q_position=qpos, interpret=True)
+            q_position=qpos, layer=layer, interpret=True)
         want = ref.ref_decode_attention_paged(
             q.astype(jnp.bfloat16).reshape(B, Hkv, Hq // Hkv, D), kp, vp,
             bt, qpos)
     assert np.all(np.isfinite(np.asarray(out, np.float32)))
     _close(out, want.reshape(B, Hq, D),
            jnp.bfloat16 if pool == "bf16" else jnp.float32)
+
+
+def _nan_layers(pool, layer):
+    """``pool`` as layer ``layer`` of an N_LAYERS stack of NaN."""
+    return jnp.full((N_LAYERS, *pool.shape), jnp.nan,
+                    pool.dtype).at[layer].set(pool)
 
 
 def test_paged_ref_matches_dense_ref():
@@ -251,6 +295,95 @@ def test_paged_ref_matches_dense_ref():
     kv_pos = ref.ref_paged_positions(bt, bs)
     want = ref.ref_decode_attention(q, k, v, kv_pos, qpos[:, None])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the decode step writes each layer's row into the pool stack in place
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("pool", ["bf16", "q8"])
+def test_forward_step_writes_each_layer_row_in_place(pool, impl):
+    """One paged decode step over a three-layer stack of random pages:
+    layer l's new K and V rows lie at [l, page, offset] of each slot's
+    page and equal the rows a dense cache of the same contents writes;
+    the shielded slot (table unmapped) writes nothing, and every other
+    byte of the stack is the input's.  An int8 page written to may be
+    rescaled whole (the monotone scale merge), so there the new row and
+    the live rows before it are held to their values dequantized; every
+    other page, and its scales, is the input's byte for byte."""
+    q8 = pool == "q8"
+    cfg = reduce_config(get_config("mistral-7b")).with_(
+        n_layers=N_LAYERS, n_kv_heads=2, sliding_window=0,
+        dtype="float32" if q8 else "bfloat16")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    B, bs, MB, NB = 3, 4, 4, 12
+    Hkv, Dh = cfg.n_kv_heads, cfg.d_head
+    lens = np.array([5, 8, 2], np.int32)  # offsets 1, 0 (a fresh page), 2
+    rng = np.random.RandomState(5)
+    bt = _rand_tables(rng, B, MB, NB, bs, lens, shielded=(2,))
+    pages = np.asarray(bt)[[0, 1], lens[:2] // bs]
+    offs = lens[:2] % bs
+    k1, k2, k3, k4, k5 = jax.random.split(jax.random.PRNGKey(5), 5)
+    shape = (N_LAYERS, NB, bs, Hkv, Dh)
+    tok = jax.random.randint(k5, (B,), 0, cfg.vocab_size, jnp.int32)
+    if q8:
+        kp, vp = (jax.random.randint(k, shape, -127, 128, jnp.int32)
+                  .astype(jnp.int8) for k in (k1, k2))
+        ks, vs = (jax.random.uniform(k, shape[:2] + (Hkv,), jnp.float32,
+                                     0.01, 0.05) for k in (k3, k4))
+        cache = PagedQ8DecodeCache(kp, vp, ks, vs, bt, jnp.asarray(lens))
+        kf = kp.astype(jnp.float32) * ks[:, :, None, :, None]
+        vf = vp.astype(jnp.float32) * vs[:, :, None, :, None]
+    else:
+        kp, vp = (jax.random.normal(k, shape, jnp.bfloat16) for k in (k1, k2))
+        cache = PagedDecodeCache(kp, vp, bt, jnp.asarray(lens))
+        kf, vf = kp, vp
+    _, new = forward_step(params, cfg, tok, cache, impl=impl)
+
+    # the dense cache of the same contents, and the rows it writes
+    S = MB * bs
+    tbl = np.maximum(np.asarray(bt), 0)
+
+    def dense_of(pf):
+        return pf[:, tbl].reshape(N_LAYERS, B, S, Hkv, Dh)
+
+    pos = np.arange(S)[None, :]
+    kv_pos = np.where((pos < lens[:, None])
+                      & np.repeat(np.asarray(bt) >= 0, bs, axis=1), pos, -1)
+    dense = DecodeCache(dense_of(kf), dense_of(vf), jnp.asarray(kv_pos),
+                        jnp.asarray(lens), None, None, None)
+    _, want = forward_step(params, cfg, tok, dense, impl="xla")
+
+    written = np.zeros((N_LAYERS, NB), bool)
+    written[:, pages] = True
+    row = np.zeros(shape[:3], bool)
+    row[:, pages, offs] = True
+    for got, old, old_f, rows, scale in (
+            (new.k, kp, kf, want.k, getattr(new, "k_scale", None)),
+            (new.v, vp, vf, want.v, getattr(new, "v_scale", None))):
+        got, old, old_f = map(np.asarray, (got, old, old_f))
+        rows = np.asarray(rows, np.float32)
+        keep = np.broadcast_to(~written[:, :, None], row.shape) if q8 \
+            else ~row
+        np.testing.assert_array_equal(got[keep], old[keep])
+        for b, (pg, off) in enumerate(zip(pages, offs)):
+            want_row = rows[:, b, lens[b]]
+            if not q8:
+                _close(got[:, pg, off], want_row, jnp.bfloat16)
+                continue
+            # the page's live rows and the new one, dequantized: within
+            # one quantization step of the old values and the dense row
+            sc = np.asarray(scale)[:, pg][:, None, :, None]  # (L,1,Hkv,1)
+            live = got[:, pg, :off + 1].astype(np.float32) * sc
+            want_live = np.concatenate(
+                [old_f[:, pg, :off], want_row[:, None]], axis=1)
+            assert np.all(np.abs(live - want_live) <= sc), \
+                (b, float(np.max(np.abs(live - want_live) / sc)))
+    if q8:
+        for got, old in ((new.k_scale, ks), (new.v_scale, vs)):
+            np.testing.assert_array_equal(np.asarray(got)[~written],
+                                          np.asarray(old)[~written])
 
 
 # ---------------------------------------------------------------------------
